@@ -20,10 +20,10 @@ bench:
 	python -m benchmarks.run
 
 serve-demo:
-	python -m repro.launch.serve --paged --requests 8 --slots 4 --new-tokens 8
+	python -m repro.launch.serve --smoke --paged --requests 8 --slots 4 --new-tokens 8
 
 # shared system prompt across all requests: prefix index dedups + skips
 # the shared prefill (DESIGN.md §9)
 serve-prefix-demo:
-	python -m repro.launch.serve --paged --prefix --requests 8 --slots 4 \
+	python -m repro.launch.serve --smoke --paged --prefix --requests 8 --slots 4 \
 		--new-tokens 8 --shared-prefix-len 32

@@ -114,8 +114,8 @@ def paged_attention_bench() -> List[Row]:
     itemsize = jnp.dtype(kv_pool_dtype).itemsize
     q1 = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
     qt = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32
     )
@@ -158,8 +158,8 @@ def paged_attention_bench() -> List[Row]:
     # -- length-bucketed dispatch raggedness sweep (DESIGN.md §11) --------
     bB, bbs, bmb, bnb = 8, 8, 32, 64
     bq = jnp.asarray(rng.normal(size=(bB, H, hd)), jnp.float32)
-    bkp = jnp.asarray(rng.normal(size=(bnb, bbs, KV, hd)), jnp.float32)
-    bvp = jnp.asarray(rng.normal(size=(bnb, bbs, KV, hd)), jnp.float32)
+    bkp = jnp.asarray(rng.normal(size=(bnb, KV, bbs, hd)), jnp.float32)
+    bvp = jnp.asarray(rng.normal(size=(bnb, KV, bbs, hd)), jnp.float32)
     bbt = jnp.asarray(
         rng.integers(1, bnb, size=(bB, bmb)), jnp.int32
     )

@@ -26,7 +26,7 @@ from repro.configs import SHAPES, get_config
 from repro.dist.sharding import SERVE_RULES, TRAIN_RULES, sharding_rules
 from repro.launch import specs as S
 from repro.launch.dryrun import build_lowered
-from repro.launch.mesh import make_production_mesh, mesh_chips
+from repro.launch.mesh import make_mesh, make_production_mesh, mesh_chips
 from repro.launch.roofline import (
     analyze_compiled,
     analytic_bytes_for_cell,
@@ -42,7 +42,7 @@ def run_variant(arch, shape_name, *, rules=None, cfg_override=None,
         cfg = dataclasses.replace(cfg, **cfg_override)
     shape = SHAPES[shape_name]
     if mesh_shape is not None:  # same 256 chips, different logical split
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh()
     base_rules = TRAIN_RULES if shape.kind == "train" else SERVE_RULES

@@ -27,6 +27,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     from .kernel_bench import (
         bucketed_serve_smoke,
         kernel_bench,

@@ -51,8 +51,8 @@ zero registry calls:
     tel.latency_summary()["ttft_s"]["p99"]   # exact percentiles
     tel.registry.prometheus()                # text snapshot
 
-CLI:  PYTHONPATH=src python -m repro.launch.serve --paged --quantize
-      PYTHONPATH=src python -m repro.launch.serve --paged --prefix --metrics
+CLI:  PYTHONPATH=src python -m repro.launch.serve --smoke --paged --quantize
+      PYTHONPATH=src python -m repro.launch.serve --smoke --paged --prefix --metrics
 Bench: PYTHONPATH=src python -m benchmarks.serve_bench   (dense vs paged)
        PYTHONPATH=src python -m benchmarks.prefix_bench  (shared prefix)
 """

@@ -16,14 +16,3 @@ Layers:
 """
 
 __version__ = "1.0.0"
-
-# jax < 0.5 compat: shard_map graduated from jax.experimental to the top
-# level; alias it so call sites (and the subprocess tests) can use the
-# modern spelling on either version.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _jax.shard_map = _shard_map
-del _jax

@@ -36,6 +36,7 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr
 
 from .findings import Finding
 
@@ -66,7 +67,7 @@ def _sub_jaxprs(eqn):
     out = []
 
     def visit(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, ClosedJaxpr):
             out.append(v.jaxpr)
         elif hasattr(v, "eqns") and hasattr(v, "invars"):
             out.append(v)
@@ -110,7 +111,7 @@ def _check_pallas_operands(eqn, where, pool_nbytes, findings):
     if gm is None:
         return
     for i, bm in enumerate(getattr(gm, "block_mappings", ())):
-        asd = getattr(bm, "array_shape_dtype", None)
+        asd = getattr(bm, "array_aval", None)
         if asd is None:
             continue
         nbytes = math.prod(asd.shape) * jnp.dtype(asd.dtype).itemsize
@@ -128,7 +129,7 @@ def _check_pallas_operands(eqn, where, pool_nbytes, findings):
 
 
 def lint_jaxpr(
-    closed: "jax.core.ClosedJaxpr",
+    closed: "ClosedJaxpr",
     where: str,
     pool_nbytes: Optional[int] = None,
     expected_switch_branches: Optional[int] = None,
@@ -192,7 +193,7 @@ def lint_jaxpr(
             branches = eqn.params.get("branches", ())
             if len(branches) > 1 and any(
                 _contains_pallas(
-                    b.jaxpr if isinstance(b, jax.core.ClosedJaxpr) else b
+                    b.jaxpr if isinstance(b, ClosedJaxpr) else b
                 )
                 for b in branches
             ):

@@ -75,20 +75,13 @@ def reduction_latency_model(
 # shard_map collective implementations
 # ---------------------------------------------------------------------------
 
-def _axis_size(axis: str) -> int:
-    if hasattr(lax, "axis_size"):          # jax >= 0.5
-        return lax.axis_size(axis)
-    return jax.core.get_axis_env().axis_size(axis) if hasattr(
-        jax.core, "get_axis_env") else lax.psum(1, axis)
-
-
 def allreduce_linear(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """SPAR-2-style linear shift-add ring: P-1 sequential permute+add.
 
     Deliberately latency-suboptimal (the paper's 'Very Slow' row) — kept as
     the baseline the Gold Standard fit must flag as out-of-range.
     """
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     acc = x
     buf = x
     perm = [(i, (i + 1) % p) for i in range(p)]
@@ -101,7 +94,7 @@ def allreduce_linear(x: jnp.ndarray, axis: str) -> jnp.ndarray:
 def allreduce_binary_hopping(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """PiCaSO binary-hopping == recursive doubling: log2(P) hops of
     stride 2^h. Every device ends with the full sum."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if p & (p - 1):
         raise ValueError("binary-hopping needs a power-of-two axis size")
     acc = x
@@ -117,7 +110,7 @@ def reduce_to_zero_binary_hopping(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """East->west accumulation onto index 0 (IMAGine's west column):
     at level h, device j receives from j + 2^h for j % 2^(h+1) == 0.
     Other devices keep garbage partials (masked out by caller)."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     if p & (p - 1):
         raise ValueError("binary-hopping needs a power-of-two axis size")
     acc = x
@@ -142,7 +135,7 @@ def reduce_scatter_then_gather(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """Bandwidth-optimal all-reduce = reduce-scatter + all-gather, written
     explicitly so the dry-run can compare collective bytes against psum.
     Operates on the flattened (padded) tensor so any shard shape works."""
-    p = _axis_size(axis)
+    p = lax.axis_size(axis)
     shape = x.shape
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % p

@@ -12,8 +12,10 @@ Terms (seconds), per the assignment spec:
     memory     = HLO_bytes        / (chips * hbm_bw)
     collective = collective_bytes / (chips * ici_bw)
 
-All constants are for the target TPU v5e (this container is CPU-only; the
-terms are derived from compiled artifacts, never wall-clock).
+The constants are published peaks, looked up by the device's
+`device_kind` in `CHIPS_BY_DEVICE_KIND`; the terms are derived from
+compiled artifacts or byte counts, never wall-clock, so they are
+predictions wherever they are printed.
 """
 
 from __future__ import annotations
@@ -50,6 +52,30 @@ TPU_V5E = ChipSpec(
     ici_bandwidth=50e9,
     ici_links=4,
 )
+
+
+#: roofline constants by `jax.Device.device_kind` — the one table every
+#: roofline prediction is priced from. A TPU kind missing here is an
+#: error, never a silent default.
+CHIPS_BY_DEVICE_KIND: Dict[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def chip_for_device(device) -> ChipSpec:
+    """The `ChipSpec` of a `jax.Device`. A non-TPU device (a CPU test
+    run) prices its predictions against `TPU_V5E`, the deployment
+    target; an unknown TPU kind raises."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    kind = device.device_kind
+    if kind not in CHIPS_BY_DEVICE_KIND:
+        raise ValueError(
+            f"no roofline constants for TPU device kind {kind!r}; add it to "
+            "core.tpu_gold.CHIPS_BY_DEVICE_KIND with its published peaks"
+        )
+    return CHIPS_BY_DEVICE_KIND[kind]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .bitplane_gemv import fit_block, unpack_digits
+
 
 def _gemm_kernel(xr_ref, planes_ref, out_ref, *, n_bits: int, group: int):
     k_idx = pl.program_id(2)
@@ -32,9 +34,9 @@ def _gemm_kernel(xr_ref, planes_ref, out_ref, *, n_bits: int, group: int):
     nd = -(-n_bits // group)
     acc = jnp.zeros(out_ref.shape, jnp.float32)
     for j in range(nd):
-        plane = planes_ref[j]
+        plane = planes_ref[j].astype(jnp.int32)
         for r in range(dpb):
-            digits = ((plane >> (group * r)) & digit_mask).astype(xr_ref.dtype)
+            digits = unpack_digits(plane, r, group, digit_mask, xr_ref.dtype)
             acc = acc + float(2 ** (group * j)) * jnp.dot(
                 xr_ref[r], digits, preferred_element_type=jnp.float32
             )
@@ -51,7 +53,7 @@ def bitplane_gemm(
     *,
     n_bits: int,
     group: int = 1,
-    block_b: int = 256,
+    block_b: int = 128,
     block_m: int = 256,
     block_k8: int = 128,
     interpret: bool = False,
@@ -60,13 +62,10 @@ def bitplane_gemm(
     nd, k8p, m = planes.shape
     assert k8p == k8
     block_b = min(block_b, b)
-    block_m = min(block_m, m)
-    block_k8 = min(block_k8, k8)
-    if b % block_b or m % block_m or k8 % block_k8:
-        raise ValueError(
-            f"B={b}/M={m}/K8={k8} not divisible by blocks "
-            f"{block_b}/{block_m}/{block_k8}"
-        )
+    if b % block_b:
+        raise ValueError(f"B={b} not divisible by block_b={block_b}")
+    block_m = fit_block(m, block_m)
+    block_k8 = fit_block(k8, block_k8)
     grid = (b // block_b, m // block_m, k8 // block_k8)
     kernel = functools.partial(_gemm_kernel, n_bits=n_bits, group=group)
     return pl.pallas_call(

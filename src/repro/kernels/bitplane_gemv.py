@@ -31,6 +31,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def fit_block(dim: int, pref: int) -> int:
+    """Block size along a dimension of length `dim`: `pref` when it
+    divides, else the largest multiple of 128 below `pref` that divides,
+    else the whole dimension. Real projection widths are often not
+    multiples of the preferred tile (qwen2-1.5b has K8 = 192 and 1120),
+    and a whole dimension is always a legal block."""
+    if pref <= dim and dim % pref == 0:
+        return pref
+    for b in range(min(pref, dim) // 128 * 128, 0, -128):
+        if dim % b == 0:
+            return b
+    return dim
+
+
+def unpack_digits(plane, r, group: int, digit_mask: int, dtype):
+    """Digit `r` of each packed byte, as `dtype`. `plane` is already
+    int32: Mosaic shifts and converts 32-bit integers, but has no
+    uint8 -> float cast."""
+    digits = (plane >> (group * r)) & digit_mask
+    return digits.astype(jnp.float32).astype(dtype)
+
+
 def _gemv_kernel(xr_ref, planes_ref, out_ref, *, n_bits: int, group: int):
     """One (m, k) grid step: accumulate all digit planes of this K tile."""
     k_idx = pl.program_id(1)
@@ -44,9 +66,9 @@ def _gemv_kernel(xr_ref, planes_ref, out_ref, *, n_bits: int, group: int):
     nd = -(-n_bits // group)
     acc = jnp.zeros(out_ref.shape, jnp.float32)
     for j in range(nd):
-        plane = planes_ref[j]  # [bk8, bm] uint8
+        plane = planes_ref[j].astype(jnp.int32)  # [bk8, bm] uint8 -> int32
         for r in range(dpb):
-            digits = ((plane >> (group * r)) & digit_mask).astype(xr_ref.dtype)
+            digits = unpack_digits(plane, r, group, digit_mask, xr_ref.dtype)
             part = jnp.dot(
                 xr_ref[r], digits, preferred_element_type=jnp.float32
             )
@@ -70,17 +92,16 @@ def bitplane_gemv(
 ) -> jnp.ndarray:
     """Raw plane contraction: returns f32 [B, M] = x @ (W_q + off).
 
-    The caller (ops.bitplane_matmul) applies the offset correction and the
+    `block_m`/`block_k8` are preferred tiles, fitted to the shape by
+    `fit_block`. The caller (ops.bitplane_matmul) applies the offset correction and the
     per-channel scale epilogue.
     """
     dpb, b, k8 = x_r.shape
     nd, k8p, m = planes.shape
     assert k8p == k8, (k8p, k8)
     assert dpb == 8 // group
-    block_m = min(block_m, m)
-    block_k8 = min(block_k8, k8)
-    if m % block_m or k8 % block_k8:
-        raise ValueError(f"M={m}/K8={k8} not divisible by blocks {block_m}/{block_k8}")
+    block_m = fit_block(m, block_m)
+    block_k8 = fit_block(k8, block_k8)
 
     grid = (m // block_m, k8 // block_k8)
     kernel = functools.partial(_gemv_kernel, n_bits=n_bits, group=group)

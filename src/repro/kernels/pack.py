@@ -18,17 +18,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .bitplane_gemv import fit_block
+
 
 def _pack_kernel(ur_ref, out_ref, *, n_bits: int, group: int):
     dpb = 8 // group
     digit_mask = (1 << group) - 1
     nd = -(-n_bits // group)
     for j in range(nd):
-        acc = jnp.zeros(out_ref.shape[1:], jnp.uint8)
+        acc = jnp.zeros(out_ref.shape[1:], jnp.int32)
         for r in range(dpb):
-            digit = (ur_ref[r] >> (group * j)) & digit_mask  # uint8 [bk8, bm]
-            acc = acc | (digit << (group * r)).astype(jnp.uint8)
-        out_ref[j] = acc
+            # 32-bit shifts: Mosaic's integer vector ops are 32-bit
+            u = ur_ref[r].astype(jnp.int32)               # [bk8, bm]
+            acc = acc | (((u >> (group * j)) & digit_mask) << (group * r))
+        out_ref[j] = acc.astype(jnp.uint8)
 
 
 @functools.partial(
@@ -46,10 +49,8 @@ def pack_bitplanes(
     dpb, k8, m = u_r.shape
     assert dpb == 8 // group
     nd = -(-n_bits // group)
-    block_k8 = min(block_k8, k8)
-    block_m = min(block_m, m)
-    if k8 % block_k8 or m % block_m:
-        raise ValueError(f"K8={k8}/M={m} not divisible by {block_k8}/{block_m}")
+    block_k8 = fit_block(k8, block_k8)
+    block_m = fit_block(m, block_m)
     grid = (k8 // block_k8, m // block_m)
     kernel = functools.partial(_pack_kernel, n_bits=n_bits, group=group)
     return pl.pallas_call(

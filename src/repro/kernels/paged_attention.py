@@ -19,7 +19,8 @@ scratch; the last step normalizes and writes the slot's output block.
 
 Layouts:
     q            [B, H, hd]                 one query token per slot
-    k/v_pages    [n_blocks, bs, KV, hd]     the shared page pool (ANY/HBM)
+    k/v_pages    [n_blocks, KV, bs, hd]     the shared page pool (ANY/HBM),
+                                            head-major pages
     block_table  [B, max_blocks] int32      page id of slot b's j-th page
     lengths      [B] int32                  valid kv count (ragged)
     window       [1] int32                  sliding window (cache capacity
@@ -45,14 +46,13 @@ from jax.experimental.pallas import tpu as pltpu
 from . import ref
 from .ops import resolve_impl
 from .paged_common import (
-    NEG_INF,
     bucketed_page_dispatch,
     check_quantized_operands as _check_quantized,
     double_buffered_page_walk,
     effective_walk_start,
     finalize_online_softmax,
-    load_kv_page,
-    online_softmax_fold,
+    fold_kv_page,
+    page_scale_rows,
     reset_online_softmax,
 )
 
@@ -64,36 +64,32 @@ def _paged_decode_kernel(
     len_ref,      # [B] int32
     win_ref,      # [1] int32
     # blocked / ANY operands, then outputs, then scratch — the exact
-    # tuple depends on `quantized` (int8 pools add the two per-page
-    # scale arrays, their landing buffers, and two semaphore lanes)
+    # tuple depends on `quantized` (int8 pools add the slot's two scale
+    # row blocks after the pools)
     *refs,
     # float path refs:
-    #   q_ref [1, H, hd] VMEM | kp_hbm, vp_hbm [n_blocks, bs, KV, hd]
-    #   ANY/HBM | out_ref [1, H, hd] f32 VMEM | k_buf, v_buf
-    #   [2, bs, KV, hd] | m_s, l_s [KV, g] f32 | acc_s [KV, g, hd] f32 |
-    #   sem [2, 2]
-    # quantized path inserts ks_hbm/vs_hbm [n_blocks, KV] f32 after the
-    # pools, ks_buf/vs_buf [2, KV] f32 after the page buffers, and sem
-    # widens to [2, 4]
-    n_kv: int,
+    #   q_ref [1, KV, g, hd] VMEM | kp_hbm, vp_hbm [n_blocks, KV, bs, hd]
+    #   ANY/HBM | out_ref [1, KV, g, hd] f32 VMEM | k_buf, v_buf
+    #   [2, KV, bs, hd] | m_s, l_s [KV, g, 1] f32 | acc_s [KV, g, hd] f32
+    #   | sem [2, 2]
+    # quantized path inserts ks_ref/vs_ref [1, max_blocks, KV] f32 VMEM
+    # (this slot's per-page scale rows) after the pools
     block_size: int,
     depth: int,   # walk depth of THIS launch (<= table width)
     quantized: bool,
 ):
     if quantized:
-        (q_ref, kp_hbm, vp_hbm, ks_hbm, vs_hbm, out_ref,
-         k_buf, v_buf, ks_buf, vs_buf, m_s, l_s, acc_s, sem) = refs
+        (q_ref, kp_hbm, vp_hbm, ks_ref, vs_ref, out_ref,
+         k_buf, v_buf, m_s, l_s, acc_s, sem) = refs
     else:
         (q_ref, kp_hbm, vp_hbm, out_ref,
          k_buf, v_buf, m_s, l_s, acc_s, sem) = refs
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+        ks_ref = vs_ref = None
     i = pl.program_id(0)               # slot
     j = pl.program_id(1)               # kv block within the slot's table
     n_steps = pl.num_programs(0) * depth
     step = i * depth + j
     mb = bt_ref.shape[1]
-    h, hd = q_ref.shape[1], q_ref.shape[2]
-    g = h // n_kv
 
     # double-buffered DMA: warm up step 0, prefetch step+1, wait step.
     # The walk covers table columns [start, start + depth) — a windowed
@@ -101,7 +97,6 @@ def _paged_decode_kernel(
     cur = double_buffered_page_walk(
         step, n_steps, bt_ref, depth, kp_hbm, vp_hbm, k_buf, v_buf, sem,
         start_ref=st_ref,
-        ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf, vs_buf=vs_buf,
     )
 
     # -- online-softmax fold (identical math to the ref oracle) -----------
@@ -112,28 +107,24 @@ def _paged_decode_kernel(
     length = len_ref[i]
     window = win_ref[0]
     q_pos = length - 1
-    qf = q_ref[0].reshape(n_kv, g, hd).astype(jnp.float32) * (hd ** -0.5)
-    kj, vj = load_kv_page(k_buf, v_buf, cur, ks_buf, vs_buf)
-
-    scores = jnp.einsum("kgh,skh->kgs", qf, kj)          # [KV, g, bs]
     col = effective_walk_start(st_ref, i, depth, mb) + j
     kv_pos = col * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1
     )                                                    # [1, bs] (2D: TPU)
     ok = (kv_pos < length) & (kv_pos > q_pos - window)
-    online_softmax_fold(
-        m_s, l_s, acc_s, scores, ok[None], vj, "kgs,skh->kgh"
+    fold_kv_page(
+        q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s, ks_ref, vs_ref, col
     )
 
     @pl.when(j == depth - 1)
     def _():
-        out_ref[0] = finalize_online_softmax(l_s, acc_s).reshape(h, hd)
+        finalize_online_softmax(l_s, acc_s, out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,            # [B, H, hd]
-    k_pages: jnp.ndarray,      # [n_blocks, bs, KV, hd]
+    k_pages: jnp.ndarray,      # [n_blocks, KV, bs, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_blocks] int32
     lengths: jnp.ndarray,      # [B] int32
@@ -158,11 +149,11 @@ def paged_decode_attention(
     true first live block is bit-exact (start 0 = the full walk).
 
     `k_scales`/`v_scales` are required iff the pools are int8
-    (DESIGN.md §16): the walk then streams each page's scale row beside
-    it and the fold dequantizes in-register — same kernel body, no
+    (DESIGN.md §16): each slot's scale rows then ride beside its block
+    table and the fold dequantizes in-register — same kernel body, no
     second code path."""
     b, h, hd = q.shape
-    n_blocks, bs, n_kv, hd2 = k_pages.shape
+    n_blocks, n_kv, bs, hd2 = k_pages.shape
     assert hd2 == hd, (hd2, hd)
     assert h % n_kv == 0, (h, n_kv)
     quantized = _check_quantized(k_pages, k_scales, v_scales)
@@ -174,49 +165,50 @@ def paged_decode_attention(
     if block_start is None:
         block_start = jnp.zeros((b,), jnp.int32)
     kernel = functools.partial(
-        _paged_decode_kernel, n_kv=n_kv, block_size=bs, depth=depth,
+        _paged_decode_kernel, block_size=bs, depth=depth,
         quantized=quantized,
     )
-    pool_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * (
-        4 if quantized else 2
-    )
-    scale_scratch = (
-        [pltpu.VMEM((2, n_kv), jnp.float32)] * 2 if quantized else []
+    pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 + (
+        [pl.BlockSpec((1, mb, n_kv), lambda i, j, *_: (i, 0, 0))] * 2
+        if quantized else []
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,   # block_table, block_start, lengths, window
         grid=(b, depth),
         in_specs=[
-            pl.BlockSpec((1, h, hd), lambda i, j, *_: (i, 0, 0)),
-            *pool_specs,         # K/V pools (+ scale arrays) stay in HBM
+            pl.BlockSpec((1, n_kv, g, hd), lambda i, j, *_: (i, 0, 0, 0)),
+            *pool_specs,         # K/V pools stay in HBM (+ scale rows)
         ],
-        out_specs=pl.BlockSpec((1, h, hd), lambda i, j, *_: (i, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, n_kv, g, hd), lambda i, j, *_: (i, 0, 0, 0)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((2, bs, n_kv, hd), k_pages.dtype),
-            pltpu.VMEM((2, bs, n_kv, hd), v_pages.dtype),
-            *scale_scratch,
-            pltpu.VMEM((n_kv, g), jnp.float32),
-            pltpu.VMEM((n_kv, g), jnp.float32),
+            pltpu.VMEM((2, n_kv, bs, hd), k_pages.dtype),
+            pltpu.VMEM((2, n_kv, bs, hd), v_pages.dtype),
+            pltpu.VMEM((n_kv, g, 1), jnp.float32),
+            pltpu.VMEM((n_kv, g, 1), jnp.float32),
             pltpu.VMEM((n_kv, g, hd), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     pools = (
-        (k_pages, v_pages, k_scales, v_scales) if quantized
+        (k_pages, v_pages, page_scale_rows(k_scales, block_table),
+         page_scale_rows(v_scales, block_table)) if quantized
         else (k_pages, v_pages)
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
         interpret=interpret,
     )(block_table.astype(jnp.int32), block_start.astype(jnp.int32),
-      lengths.astype(jnp.int32), win, q, *pools)
+      lengths.astype(jnp.int32), win, q.reshape(b, n_kv, g, hd), *pools)
+    return out.reshape(b, h, hd)
 
 
 def paged_decode_attention_bucketed(
     q: jnp.ndarray,            # [B, H, hd]
-    k_pages: jnp.ndarray,      # [n_blocks, bs, KV, hd]
+    k_pages: jnp.ndarray,      # [n_blocks, KV, bs, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_blocks] int32
     lengths: jnp.ndarray,      # [B] int32

@@ -31,38 +31,60 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: scoped-VMEM limit of the paged kernels. A prefill launch holds its
+#: slot's whole query block, output block and fold state in VMEM (about
+#: 3 KiB per query row and KV head), which passes the 16 MiB default at
+#: a few thousand rows; a v5e core has 128 MiB.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
 #: symmetric int8 quantization ceiling — codes live in [-128, 127], the
 #: per-(page, head) absmax maps to ±127 (same convention as the weight
 #: path's `ref.quantize_ref`)
 INT8_QMAX = 127.0
 
 
+def pages_to_tokens(pages):
+    """Head-major pages [..., n, KV, bs, hd] -> token rows
+    [..., n * bs, KV, hd], in page order."""
+    *lead, n, kv, bs, hd = pages.shape
+    return jnp.swapaxes(pages, -3, -2).reshape(*lead, n * bs, kv, hd)
+
+
+def tokens_to_pages(rows, block_size: int):
+    """Inverse of `pages_to_tokens`: token rows [..., S, KV, hd] (S a
+    multiple of `block_size`) -> head-major pages [..., S/bs, KV, bs,
+    hd]."""
+    *lead, s, kv, hd = rows.shape
+    paged = rows.reshape(*lead, s // block_size, block_size, kv, hd)
+    return jnp.swapaxes(paged, -3, -2)
+
+
 def quantize_pages(pages):
     """Per-page, per-head symmetric int8 quantization of KV pages.
 
-    `pages` is float [..., bs, KV, hd] (any number of leading page
-    axes); returns `(codes int8 [..., bs, KV, hd], scales f32 [..., KV])`
+    `pages` is float [..., KV, bs, hd] (any number of leading page
+    axes); returns `(codes int8 [..., KV, bs, hd], scales f32 [..., KV])`
     with `scale = absmax / 127` over each page's (bs, hd) plane per KV
     head. All-zero planes take scale 1.0 (the guard keeps dequant exact
     at 0 and division well-defined), matching `ref.quantize_ref`.
     """
     f = pages.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(f), axis=(-3, -1))
+    absmax = jnp.max(jnp.abs(f), axis=(-2, -1))
     scales = jnp.where(absmax > 0, absmax / INT8_QMAX, 1.0)
     scales = scales.astype(jnp.float32)
     codes = jnp.clip(
-        jnp.round(f / scales[..., None, :, None]),
+        jnp.round(f / scales[..., None, None]),
         -(INT8_QMAX + 1), INT8_QMAX,
     ).astype(jnp.int8)
     return codes, scales
 
 
 def dequantize_pages(codes, scales):
-    """Inverse of `quantize_pages`: int8 codes [..., bs, KV, hd] times
+    """Inverse of `quantize_pages`: int8 codes [..., KV, bs, hd] times
     their per-(page, head) f32 scales [..., KV] -> f32 pages. The single
     home of code->float conversion (RL206); the oracles and the append
     path both route through here."""
-    return codes.astype(jnp.float32) * scales[..., None, :, None]
+    return codes.astype(jnp.float32) * scales[..., None, None]
 
 
 def requantize_page_update(codes, scales, update_fn):
@@ -98,18 +120,31 @@ def check_quantized_operands(k_pages, k_scales, v_scales) -> bool:
     return bool(quantized)
 
 
-def load_kv_page(k_buf, v_buf, cur, ks_buf=None, vs_buf=None):
-    """Read buffer `cur`'s K/V page as f32 for the fold — the
-    in-register dequant point of the quantized path (DESIGN.md §16):
-    with scale buffers riding the walk, each code multiplies its page's
-    per-head scale right here, between the DMA wait and the softmax
-    fold. `ks_buf=None` is the float path (plain astype, unchanged
-    math)."""
-    kj = k_buf[cur].astype(jnp.float32)
-    vj = v_buf[cur].astype(jnp.float32)
-    if ks_buf is not None:
-        kj = kj * ks_buf[cur].reshape(1, -1, 1)
-        vj = vj * vs_buf[cur].reshape(1, -1, 1)
+def page_scale_rows(scales, block_table):
+    """Per-slot scale rows of one launch, `scales[block_table]` ->
+    f32 [B, max_blocks, KV]: the table's pages' scales gathered in XLA
+    and handed to the kernel as a blocked VMEM operand. A per-page DMA
+    of a `[1, KV]` scale row is refused by Mosaic (a lane slice narrower
+    than the 128-lane tile), so the scales travel with the slot's table
+    row and the fold picks its page's row by table column."""
+    return scales[block_table].astype(jnp.float32)
+
+
+def load_kv_page(k_buf, v_buf, cur, head, scale_rows=None):
+    """Read KV head `head` of buffer `cur`'s K/V page as f32 [bs, hd] —
+    the in-register dequant point of the quantized path (DESIGN.md §16):
+    with `scale_rows` (this page's `(k_row, v_row)`, each f32 [1, KV])
+    every code multiplies its page's per-head scale right here, between
+    the DMA wait and the softmax fold. `scale_rows=None` is the float
+    path (plain astype, unchanged math)."""
+    kj = k_buf[cur, head].astype(jnp.float32)
+    vj = v_buf[cur, head].astype(jnp.float32)
+    if scale_rows is not None:
+        # a scalar, not a [1, 1] slice: Mosaic cannot broadcast [1, 1]
+        # across sublanes and lanes at once
+        k_row, v_row = scale_rows
+        kj = kj * k_row[0, head]
+        vj = vj * v_row[0, head]
     return kj, vj
 
 
@@ -141,38 +176,30 @@ def double_buffered_page_walk(
                   # table when a bucketed dispatch bounds the grid
                   # (DESIGN.md §11); pages at column >= depth are never
                   # visited
-    kp_hbm,       # [n_blocks, bs, KV, hd] K pool — ANY/HBM ref
+    kp_hbm,       # [n_blocks, KV, bs, hd] K pool — ANY/HBM ref
     vp_hbm,       # V pool
-    k_buf,        # [2, bs, KV, hd] VMEM landing buffers
+    k_buf,        # [2, KV, bs, hd] VMEM landing buffers
     v_buf,
-    sem,          # DMA semaphores [2 buffers, 2 pools] (float path) or
-                  # [2, 4] when the scale rows ride along (int8 path)
+    sem,          # DMA semaphores [2 buffers, 2 pools]
     start_ref=None,  # [B] int32 first live block per slot (scalar
                      # prefetch) — None keeps the column-0 walk
-    ks_hbm=None,  # [n_blocks, KV] f32 per-page K scales — ANY/HBM ref
-                  # (quantized pools only, DESIGN.md §16)
-    vs_hbm=None,  # V scales
-    ks_buf=None,  # [2, KV] f32 VMEM scale landing buffers
-    vs_buf=None,
 ):
     """Run one grid step of the double-buffered block walk: start the
     copies for step+1, wait for this step's pages, and return the buffer
-    index now holding them (read `k_buf[cur]` / `v_buf[cur]`, or
-    `load_kv_page` to fold the scale rows in). On quantized pools the
-    per-page scale rows are double-buffered with the same schedule as
-    their pages — two extra (tiny) DMAs per step on semaphore lanes
-    2/3."""
+    index now holding them (read them through `load_kv_page`). Pages are
+    head-major, so one copy moves a whole `[KV, bs, hd]` page whose
+    minor (bs, hd) plane is tile-aligned for every pool dtype (an int8
+    `[bs, KV, hd]` page is not: its KV axis pads to the int8 tile)."""
     table_width = bt_ref.shape[1]
 
     def page_copies(s, slot):
-        """The async page copies (K and V pools, plus their scale rows on
-        quantized pools) of linear step `s` into buffer `slot` —
-        recreated identically to start and to wait."""
+        """The async page copies (K and V pools) of linear step `s` into
+        buffer `slot` — recreated identically to start and to wait."""
         col = effective_walk_start(
             start_ref, s // depth, depth, table_width
         ) + s % depth
         page = bt_ref[s // depth, col]
-        copies = (
+        return (
             pltpu.make_async_copy(
                 kp_hbm.at[pl.ds(page, 1)], k_buf.at[pl.ds(slot, 1)],
                 sem.at[slot, 0],
@@ -182,18 +209,6 @@ def double_buffered_page_walk(
                 sem.at[slot, 1],
             ),
         )
-        if ks_hbm is not None:
-            copies += (
-                pltpu.make_async_copy(
-                    ks_hbm.at[pl.ds(page, 1)], ks_buf.at[pl.ds(slot, 1)],
-                    sem.at[slot, 2],
-                ),
-                pltpu.make_async_copy(
-                    vs_hbm.at[pl.ds(page, 1)], vs_buf.at[pl.ds(slot, 1)],
-                    sem.at[slot, 3],
-                ),
-            )
-        return copies
 
     @pl.when(step == 0)
     def _():
@@ -218,28 +233,51 @@ def reset_online_softmax(m_s, l_s, acc_s):
     acc_s[...] = jnp.zeros_like(acc_s)
 
 
-def online_softmax_fold(m_s, l_s, acc_s, scores, ok, vj, v_spec: str):
-    """Fold one page of `scores` (last axis = page rows) into the carried
-    (m, l, acc) state. `ok` is the validity mask broadcast to `scores`;
-    masked rows score NEG_INF, and their unit contributions while the
-    running max is still NEG_INF cancel later through the alpha rescale
-    (the oracle computes don't-care rows the same way — parity).
-    `v_spec` contracts the probabilities with the page's values
-    (decode "kgs,skh->kgh", prefill "kgts,skh->kgth")."""
-    scores = jnp.where(ok, scores, NEG_INF)
-    m, l, acc = m_s[...], l_s[...], acc_s[...]
-    m_new = jnp.maximum(m, scores.max(axis=-1))
-    p = jnp.exp(scores - m_new[..., None])
-    alpha = jnp.exp(m - m_new)
-    m_s[...] = m_new
-    l_s[...] = alpha * l + p.sum(axis=-1)
-    acc_s[...] = alpha[..., None] * acc + jnp.einsum(v_spec, p, vj)
+def fold_kv_page(q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s,
+                 ks_ref=None, vs_ref=None, col=None):
+    """Fold buffer `cur`'s page into the carried (m, l, acc) state of
+    every query row, one KV head at a time.
+
+    `q_ref` is the slot's head-major query block [1, KV, R, hd] (R rows
+    per KV head: the g query heads of the group, times T suffix tokens in
+    prefill); `ok` is the validity mask, broadcastable to [R, bs]; the
+    state refs are m/l [KV, R, 1] and acc [KV, R, hd]. Each head is a
+    plain 2-D matmul pair, so nothing inside the kernel reshapes across
+    the (sublane, lane) tile. Masked rows score NEG_INF, and their unit
+    contributions while the running max is still NEG_INF cancel later
+    through the alpha rescale (the oracle computes don't-care rows the
+    same way — parity). On quantized pools `ks_ref`/`vs_ref` are the
+    slot's scale rows [1, max_blocks, KV] and `col` the page's table
+    column."""
+    scale_rows = None
+    if ks_ref is not None:
+        scale_rows = (ks_ref[0, pl.ds(col, 1), :], vs_ref[0, pl.ds(col, 1), :])
+    n_kv, hd = q_ref.shape[1], q_ref.shape[3]
+    for h in range(n_kv):
+        qf = q_ref[0, h].astype(jnp.float32) * (hd ** -0.5)     # [R, hd]
+        kj, vj = load_kv_page(k_buf, v_buf, cur, h, scale_rows)
+        scores = jax.lax.dot_general(
+            qf, kj, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                       # [R, bs]
+        scores = jnp.where(ok, scores, NEG_INF)
+        m, l, acc = m_s[h], l_s[h], acc_s[h]
+        m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_s[h] = m_new
+        l_s[h] = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc_s[h] = alpha * acc + jnp.dot(
+            p, vj, preferred_element_type=jnp.float32
+        )
 
 
-def finalize_online_softmax(l_s, acc_s):
-    """Normalize the carried state; max(l, eps) keeps fully-masked rows
-    finite (matching the oracles' don't-care semantics)."""
-    return acc_s[...] / jnp.maximum(l_s[...], 1e-30)[..., None]
+def finalize_online_softmax(l_s, acc_s, out_ref):
+    """Normalize the carried state into the slot's output block
+    [1, KV, R, hd]; max(l, eps) keeps fully-masked rows finite (matching
+    the oracles' don't-care semantics)."""
+    for h in range(acc_s.shape[0]):
+        out_ref[0, h] = acc_s[h] / jnp.maximum(l_s[h], 1e-30)
 
 
 def bucketed_page_dispatch(launch, plan, perm, block_table, slot_operands):

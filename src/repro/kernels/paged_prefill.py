@@ -30,8 +30,9 @@ Layouts:
     q            [B, T, H, hd]              suffix queries, T padded to a
                                             block multiple (RoPE applied
                                             at start + t by the caller)
-    k/v_pages    [n_blocks, bs, KV, hd]     shared pool (ANY/HBM), suffix
-                                            KV already scattered in
+    k/v_pages    [n_blocks, KV, bs, hd]     shared pool (ANY/HBM) of
+                                            head-major pages, suffix KV
+                                            already scattered in
     block_table  [B, max_blocks] int32      page id of slot b's j-th page
     start        [B] int32                  cached-prefix length per slot
     total        [B] int32                  full valid length per slot
@@ -55,14 +56,14 @@ from jax.experimental.pallas import tpu as pltpu
 from . import ref
 from .ops import resolve_impl
 from .paged_common import (
-    NEG_INF,
+    VMEM_LIMIT_BYTES,
     bucketed_page_dispatch,
     check_quantized_operands as _check_quantized,
     double_buffered_page_walk,
     effective_walk_start,
     finalize_online_softmax,
-    load_kv_page,
-    online_softmax_fold,
+    fold_kv_page,
+    page_scale_rows,
     reset_online_softmax,
 )
 
@@ -75,36 +76,35 @@ def _paged_prefill_kernel(
     total_ref,    # [B] int32
     win_ref,      # [1] int32
     # blocked / ANY operands, then outputs, then scratch — the exact
-    # tuple depends on `quantized` (int8 pools add the two per-page
-    # scale arrays, their landing buffers, and two semaphore lanes)
+    # tuple depends on `quantized` (int8 pools add the slot's two scale
+    # row blocks after the pools)
     *refs,
     # float path refs:
-    #   q_ref [1, T, H, hd] VMEM | kp_hbm, vp_hbm [n_blocks, bs, KV, hd]
-    #   ANY/HBM | out_ref [1, T, H, hd] f32 VMEM | k_buf, v_buf
-    #   [2, bs, KV, hd] | m_s, l_s [KV, g, T] f32 | acc_s [KV, g, T, hd]
+    #   q_ref [1, KV, g*T, hd] VMEM | kp_hbm, vp_hbm [n_blocks, KV, bs,
+    #   hd] ANY/HBM | out_ref [1, KV, g*T, hd] f32 VMEM | k_buf, v_buf
+    #   [2, KV, bs, hd] | m_s, l_s [KV, g*T, 1] f32 | acc_s [KV, g*T, hd]
     #   f32 | sem [2, 2]
-    # quantized path inserts ks_hbm/vs_hbm [n_blocks, KV] f32 after the
-    # pools, ks_buf/vs_buf [2, KV] f32 after the page buffers, and sem
-    # widens to [2, 4]
-    n_kv: int,
+    # quantized path inserts ks_ref/vs_ref [1, max_blocks, KV] f32 VMEM
+    # (this slot's per-page scale rows) after the pools
+    n_groups: int,  # g query heads per KV head (rows = g * T)
     block_size: int,
     depth: int,   # walk depth of THIS launch (<= table width)
     quantized: bool,
 ):
     if quantized:
-        (q_ref, kp_hbm, vp_hbm, ks_hbm, vs_hbm, out_ref,
-         k_buf, v_buf, ks_buf, vs_buf, m_s, l_s, acc_s, sem) = refs
+        (q_ref, kp_hbm, vp_hbm, ks_ref, vs_ref, out_ref,
+         k_buf, v_buf, m_s, l_s, acc_s, sem) = refs
     else:
         (q_ref, kp_hbm, vp_hbm, out_ref,
          k_buf, v_buf, m_s, l_s, acc_s, sem) = refs
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+        ks_ref = vs_ref = None
     i = pl.program_id(0)               # slot
     j = pl.program_id(1)               # kv block within the slot's table
     n_steps = pl.num_programs(0) * depth
     step = i * depth + j
     mb = bt_ref.shape[1]
-    t, h, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    g = h // n_kv
+    g = n_groups
+    t = q_ref.shape[2] // g
 
     # double-buffered DMA: warm up step 0, prefetch step+1, wait step.
     # The walk covers table columns [start, start + depth): a windowed
@@ -112,7 +112,6 @@ def _paged_prefill_kernel(
     cur = double_buffered_page_walk(
         step, n_steps, bt_ref, depth, kp_hbm, vp_hbm, k_buf, v_buf, sem,
         start_ref=blk_ref,
-        ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf, vs_buf=vs_buf,
     )
 
     # -- online-softmax fold (identical math to the ref oracle) -----------
@@ -123,13 +122,11 @@ def _paged_prefill_kernel(
     start = start_ref[i]
     total = total_ref[i]
     window = win_ref[0]
-    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)  # [T, 1]
-    qf = (
-        q_ref[0].reshape(t, n_kv, g, hd).astype(jnp.float32) * (hd ** -0.5)
-    )
-    kj, vj = load_kv_page(k_buf, v_buf, cur, ks_buf, vs_buf)
-
-    scores = jnp.einsum("tkgh,skh->kgts", qf, kj)        # [KV, g, T, bs]
+    # query row r = (group head, token) in g-major order: token t sits at
+    # logical position start + t
+    q_pos = start + jax.lax.broadcasted_iota(
+        jnp.int32, (g, t, block_size), 1
+    ).reshape(g * t, block_size)                         # [g*T, bs]
     col = effective_walk_start(blk_ref, i, depth, mb) + j
     kv_pos = col * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1
@@ -138,21 +135,20 @@ def _paged_prefill_kernel(
         (kv_pos <= q_pos)
         & (kv_pos < total)
         & (kv_pos > q_pos - window)
-    )                                                    # [T, bs]
-    online_softmax_fold(
-        m_s, l_s, acc_s, scores, ok[None, None], vj, "kgts,skh->kgth"
+    )                                                    # [g*T, bs]
+    fold_kv_page(
+        q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s, ks_ref, vs_ref, col
     )
 
     @pl.when(j == depth - 1)
     def _():
-        out = finalize_online_softmax(l_s, acc_s)        # [KV, g, T, hd]
-        out_ref[0] = out.transpose(2, 0, 1, 3).reshape(t, h, hd)
+        finalize_online_softmax(l_s, acc_s, out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "interpret"))
 def paged_prefill_attention(
     q: jnp.ndarray,            # [B, T, H, hd]
-    k_pages: jnp.ndarray,      # [n_blocks, bs, KV, hd]
+    k_pages: jnp.ndarray,      # [n_blocks, KV, bs, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_blocks] int32
     start: jnp.ndarray,        # [B] int32
@@ -176,12 +172,16 @@ def paged_prefill_attention(
     scratch and are window-masked, so any start <= the true first live
     block is bit-exact.
 
+    The queries enter the kernel head-major, `[B, KV, g*T, hd]` (an XLA
+    transpose outside the kernel), so each KV head's rows are one 2-D
+    operand and the fold never reshapes inside a tile.
+
     `k_scales`/`v_scales` are required iff the pools are int8
-    (DESIGN.md §16): the walk then streams each page's scale row beside
-    it and the fold dequantizes in-register — same kernel body, no
+    (DESIGN.md §16): each slot's scale rows then ride beside its block
+    table and the fold dequantizes in-register — same kernel body, no
     second code path."""
     b, t, h, hd = q.shape
-    n_blocks, bs, n_kv, hd2 = k_pages.shape
+    n_blocks, n_kv, bs, hd2 = k_pages.shape
     assert hd2 == hd, (hd2, hd)
     assert h % n_kv == 0, (h, n_kv)
     quantized = _check_quantized(k_pages, k_scales, v_scales)
@@ -189,54 +189,66 @@ def paged_prefill_attention(
     depth = mb if depth is None else depth
     assert 1 <= depth <= mb, (depth, mb)
     g = h // n_kv
+    rows = g * t
     win = jnp.asarray(window, jnp.int32).reshape(1)
     if block_start is None:
         block_start = jnp.zeros((b,), jnp.int32)
     kernel = functools.partial(
-        _paged_prefill_kernel, n_kv=n_kv, block_size=bs, depth=depth,
+        _paged_prefill_kernel, n_groups=g, block_size=bs, depth=depth,
         quantized=quantized,
     )
-    pool_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * (
-        4 if quantized else 2
-    )
-    scale_scratch = (
-        [pltpu.VMEM((2, n_kv), jnp.float32)] * 2 if quantized else []
+    pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 + (
+        [pl.BlockSpec((1, mb, n_kv), lambda i, j, *_: (i, 0, 0))] * 2
+        if quantized else []
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,   # table, block_start, start, total, window
         grid=(b, depth),
         in_specs=[
-            pl.BlockSpec((1, t, h, hd), lambda i, j, *_: (i, 0, 0, 0)),
-            *pool_specs,         # K/V pools (+ scale arrays) stay in HBM
+            pl.BlockSpec((1, n_kv, rows, hd), lambda i, j, *_: (i, 0, 0, 0)),
+            *pool_specs,         # K/V pools stay in HBM (+ scale rows)
         ],
-        out_specs=pl.BlockSpec((1, t, h, hd), lambda i, j, *_: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, n_kv, rows, hd), lambda i, j, *_: (i, 0, 0, 0)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((2, bs, n_kv, hd), k_pages.dtype),
-            pltpu.VMEM((2, bs, n_kv, hd), v_pages.dtype),
-            *scale_scratch,
-            pltpu.VMEM((n_kv, g, t), jnp.float32),
-            pltpu.VMEM((n_kv, g, t), jnp.float32),
-            pltpu.VMEM((n_kv, g, t, hd), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)),
+            pltpu.VMEM((2, n_kv, bs, hd), k_pages.dtype),
+            pltpu.VMEM((2, n_kv, bs, hd), v_pages.dtype),
+            pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+            pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+            pltpu.VMEM((n_kv, rows, hd), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     pools = (
-        (k_pages, v_pages, k_scales, v_scales) if quantized
+        (k_pages, v_pages, page_scale_rows(k_scales, block_table),
+         page_scale_rows(v_scales, block_table)) if quantized
         else (k_pages, v_pages)
     )
-    return pl.pallas_call(
+    q_heads = (
+        q.reshape(b, t, n_kv, g, hd).transpose(0, 2, 3, 1, 4)
+        .reshape(b, n_kv, rows, hd)
+    )
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(block_table.astype(jnp.int32), block_start.astype(jnp.int32),
       jnp.asarray(start, jnp.int32), jnp.asarray(total, jnp.int32), win,
-      q, *pools)
+      q_heads, *pools)
+    return (
+        out.reshape(b, n_kv, g, t, hd).transpose(0, 3, 1, 2, 4)
+        .reshape(b, t, h, hd)
+    )
 
 
 def paged_prefill_attention_bucketed(
     q: jnp.ndarray,            # [B, T, H, hd]
-    k_pages: jnp.ndarray,      # [n_blocks, bs, KV, hd]
+    k_pages: jnp.ndarray,      # [n_blocks, KV, bs, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_blocks] int32
     start: jnp.ndarray,        # [B] int32

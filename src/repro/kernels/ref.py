@@ -153,18 +153,17 @@ def _gather_kv_dense(pages, block_table, scales=None):
     through `paged_common.dequantize_pages` when the per-page scales ride
     along (DESIGN.md §16) — the oracle-side twin of the kernels'
     in-fold dequant."""
-    b, mb = block_table.shape
-    _, bs, kv, hd = pages.shape
-    gathered = pages[block_table]                          # [B, mb, bs, KV, hd]
+    from .paged_common import dequantize_pages, pages_to_tokens
+
+    gathered = pages[block_table]                          # [B, mb, KV, bs, hd]
     if scales is not None:
-        from .paged_common import dequantize_pages
         gathered = dequantize_pages(gathered, scales[block_table])
-    return gathered.reshape(b, mb * bs, kv, hd)
+    return pages_to_tokens(gathered)                       # [B, S, KV, hd]
 
 
 def paged_attention_ref(
     q: jnp.ndarray,            # [B, H, hd] — one query token per slot
-    k_pages: jnp.ndarray,      # [n_blocks, block_size, KV, hd]
+    k_pages: jnp.ndarray,      # [n_blocks, KV, block_size, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_blocks] int32 page ids per slot
     lengths: jnp.ndarray,      # [B] int32 valid KV count per slot
@@ -181,7 +180,7 @@ def paged_attention_ref(
     fold — the tolerance-parity anchor of the quantized kernel path.
     """
     b, h, hd = q.shape
-    _, bs, kv, _ = k_pages.shape
+    _, kv, bs, _ = k_pages.shape
     mb = block_table.shape[1]
     g = h // kv
     k = _gather_kv_dense(k_pages, block_table, k_scales)   # [B, S, KV, hd]
@@ -205,7 +204,7 @@ def paged_attention_ref(
 
 def paged_prefill_ref(
     q: jnp.ndarray,            # [B, T, H, hd] — suffix queries (T padded)
-    k_pages: jnp.ndarray,      # [n_blocks, block_size, KV, hd]
+    k_pages: jnp.ndarray,      # [n_blocks, KV, block_size, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, max_blocks] int32 page ids per slot
     start: jnp.ndarray,        # [B] int32 — position of the first suffix token
@@ -226,7 +225,7 @@ def paged_prefill_ref(
     the fold (DESIGN.md §16).
     """
     b, t, h, hd = q.shape
-    _, bs, kv, _ = k_pages.shape
+    _, kv, bs, _ = k_pages.shape
     mb = block_table.shape[1]
     g = h // kv
     k = _gather_kv_dense(k_pages, block_table, k_scales)   # [B, S, KV, hd]

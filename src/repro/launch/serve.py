@@ -1,8 +1,10 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the continuous-batching scheduler over a smoke config, optionally
-with PIM bit-plane quantized weights (the paper's technique): --quantize
-converts every projection to packed digit planes first.
+Runs the continuous-batching scheduler over the architecture's published
+config with random weights (``--smoke`` swaps in the tiny preset the CPU
+tests use), optionally with PIM bit-plane quantized weights (the paper's
+technique): --quantize converts every projection to packed digit planes
+first. The summary names the device it ran on.
 
 --paged switches to the block-paged KV cache (DESIGN.md §8): prompt
 lengths are drawn ragged per request (no shared padded length), slots
@@ -20,26 +22,39 @@ attention pattern form a group with its own page pool/tables, and
 sliding-window groups retire pages that fall behind the window
 (--no-window-retirement keeps the lockstep-residency baseline; try
 ``--arch gemma3-27b --paged`` for a mixed global/window stack).
+
+--warmup serves the same requests once before the timed drain, so the
+reported serving seconds exclude compilation. `run()` is the whole
+launch as a function; `chip_smoke.py` drives the chip through it.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..configs import get_config
+from ..configs.base import ModelConfig
 from ..models import init_lm
+from ..models.common import compute_dtype
 from ..obs import ServeTelemetry
 from ..quant.bitplane import PimQuantConfig
 from ..serve import ContinuousBatcher, Request, ServeConfig, ServeEngine
+from ..serve.compiled import trace_count
+from .compile_cache import enable_compilation_cache
 
 
-def main():
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the architecture's tiny preset (CPU tests and "
+                         "demos) instead of its published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -114,7 +129,10 @@ def main():
     ap.add_argument("--profile-annotations", action="store_true",
                     help="wrap compiled steps in jax.profiler trace "
                          "annotations / named scopes (implies --metrics)")
-    args = ap.parse_args()
+    ap.add_argument("--warmup", action="store_true",
+                    help="serve the same requests once, untimed, before "
+                         "the timed drain: every step compiles there")
+    args = ap.parse_args(argv)
     if args.prefix and not args.paged:
         ap.error("--prefix requires --paged (the prefix index shares "
                  "pages of the block-paged KV cache)")
@@ -129,9 +147,92 @@ def main():
         ap.error("--group-pool auto requires --prefill-chunk > 0: the "
                  "live-need bound that sizes each group only holds when "
                  "prefill appends are chunk-bounded (DESIGN.md §17)")
+    return args
 
-    cfg = get_config(args.arch, smoke=True)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+
+@dataclasses.dataclass
+class ServeRun:
+    """What one launch did: the batcher it drained, the tokens each
+    timed request got, and where the time went."""
+
+    cfg: ModelConfig
+    batcher: ContinuousBatcher
+    results: Dict[int, List[int]]
+    param_bytes: int
+    #: config, weights, batcher and (with --warmup) the warm-up drain
+    setup_s: float
+    #: the timed drain, ended by `block_until_ready` on the pools
+    served_s: float
+    #: serve-step traces (each one an XLA compile) in set-up / timed drain
+    compiles_setup: int
+    compiles_served: int
+    telemetry: Optional[ServeTelemetry]
+
+    @property
+    def tokens(self) -> int:
+        return sum(len(v) for v in self.results.values())
+
+
+def init_params(cfg: ModelConfig, seed: int = 0):
+    """Random weights from `seed`, stored at the compute dtype. One jitted
+    init writes the stacked layers straight into their final buffers, so
+    a published-width model never holds a second float32 copy."""
+
+    def init(key):
+        params = init_lm(key, cfg)
+        dt = compute_dtype(cfg.dtype)
+        return jax.tree.map(
+            lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating)
+            else x, params,
+        )
+
+    return jax.jit(init)(jax.random.PRNGKey(seed))
+
+
+def _prompt_len(key, uid: int, max_len: int, block_size: int) -> int:
+    """Ragged paged prompt length: a power-of-two page count (one of the
+    four largest up to ceil(max_len / block_size)) less a random part of
+    its last page. Prefill pads each prompt to whole pages, so the
+    lengths stay ragged while prefill compiles at most four widths."""
+    top = max(-(-max_len // block_size), 1).bit_length() - 1
+    exp = top - int(jax.random.randint(
+        jax.random.fold_in(key, 1000 + uid), (), 0, min(top, 3) + 1))
+    short = int(jax.random.randint(
+        jax.random.fold_in(key, 2000 + uid), (), 0, block_size))
+    return max((1 << exp) * block_size - short, 1)
+
+
+def make_requests(args, cfg: ModelConfig, uid0: int = 0) -> List[Request]:
+    """The launch's request set, the same for every call: prompts from a
+    fixed key, uids from `uid0`."""
+    key = jax.random.PRNGKey(1)
+    shared_len = args.shared_prefix_len if args.prefix else 0
+    shared = jax.random.randint(
+        jax.random.fold_in(key, 9999), (shared_len,), 0, cfg.vocab_size
+    ).astype(jnp.int32)
+    reqs = []
+    for uid in range(args.requests):
+        if args.paged:
+            t = _prompt_len(key, uid, args.prompt_len, args.block_size)
+        else:
+            t = args.prompt_len
+        prompt = jax.random.randint(
+            jax.random.fold_in(key, uid), (t,), 0, cfg.vocab_size
+        ).astype(jnp.int32)
+        if args.prefix:  # every request opens with the shared system prompt
+            prompt = jnp.concatenate([shared, prompt])
+        reqs.append(Request(uid=uid0 + uid, prompt=prompt,
+                            max_new_tokens=args.new_tokens))
+    return reqs
+
+
+def run(args: argparse.Namespace) -> ServeRun:
+    """Build the model and batcher, optionally warm up, and drain the
+    request set once under the clock."""
+    t0 = time.perf_counter()
+    traces0 = trace_count()
+    cfg = get_config(args.arch, smoke=args.smoke)
+    params = init_params(cfg)
     if args.quantize:
         eng = ServeEngine(cfg, params, ServeConfig())
         frac = eng.quantize(PimQuantConfig(n_bits=args.bits, group=args.group,
@@ -139,6 +240,7 @@ def main():
         params = eng.params
         print(f"PIM-quantized: {frac:.1%} of param bytes packed "
               f"({args.bits}-bit, group={args.group})")
+    param_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
 
     telemetry = None
     if args.metrics or args.events_out or args.profile_annotations:
@@ -162,32 +264,46 @@ def main():
         group_blocks="auto" if args.group_pool == "auto" else None,
         telemetry=telemetry,
     )
-    key = jax.random.PRNGKey(1)
-    shared = jax.random.randint(
-        jax.random.fold_in(key, 9999), (shared_len,), 0, cfg.vocab_size
-    ).astype(jnp.int32)
-    for uid in range(args.requests):
-        if args.paged:  # ragged: anywhere from 4 tokens up to --prompt-len
-            t = 4 + int(jax.random.randint(
-                jax.random.fold_in(key, 1000 + uid), (), 0,
-                max(args.prompt_len - 3, 1)))
-        else:
-            t = args.prompt_len
-        prompt = jax.random.randint(
-            jax.random.fold_in(key, uid), (t,), 0, cfg.vocab_size
-        ).astype(jnp.int32)
-        if args.prefix:  # every request opens with the shared system prompt
-            prompt = jnp.concatenate([shared, prompt])
-        batcher.submit(Request(uid=uid, prompt=prompt,
-                               max_new_tokens=args.new_tokens))
-    t0 = time.perf_counter()
-    results = batcher.run_until_drained()
-    dt = time.perf_counter() - t0
-    total_tokens = sum(len(v) for v in results.values())
+    if args.warmup:  # same prompts under other uids: every step compiles
+        for req in make_requests(args, cfg, uid0=args.requests):
+            batcher.submit(req)
+        batcher.run_until_drained()
+    jax.block_until_ready(params)
+    setup_s = time.perf_counter() - t0
+    traces1 = trace_count()
+
+    for req in make_requests(args, cfg):
+        batcher.submit(req)
+    t1 = time.perf_counter()
+    batcher.run_until_drained()
+    jax.block_until_ready(
+        (batcher.tokens, batcher.cache if batcher.pcache is None
+         else (batcher.pcache.k_pages, batcher.pcache.v_pages))
+    )
+    served_s = time.perf_counter() - t1
+    results = {u: batcher.finished[u] for u in range(args.requests)}
+    return ServeRun(
+        cfg=cfg, batcher=batcher, results=results, param_bytes=param_bytes,
+        setup_s=setup_s, served_s=served_s,
+        compiles_setup=traces1 - traces0,
+        compiles_served=trace_count() - traces1, telemetry=telemetry,
+    )
+
+
+def main(argv: Optional[List[str]] = None):
+    args = parse_args(argv)
+    enable_compilation_cache()
+    out = run(args)
+    batcher, telemetry = out.batcher, out.telemetry
+    dev = jax.devices()[0]
     mode = "paged" if args.paged else "dense"
-    print(f"served {len(results)} requests, {total_tokens} tokens "
-          f"in {dt:.2f}s ({total_tokens/dt:.1f} tok/s, {mode} cache, "
-          f"CPU smoke config)")
+    print(f"served {len(out.results)} requests, {out.tokens} tokens "
+          f"in {out.served_s:.2f}s ({out.tokens / out.served_s:.1f} tok/s, "
+          f"{mode} cache, {out.cfg.name}{' smoke' if args.smoke else ''}, "
+          f"platform={dev.platform}, device_kind={dev.device_kind})")
+    print(f"  set-up {out.setup_s:.2f}s, param bytes {out.param_bytes}, "
+          f"step compiles: {out.compiles_setup} in set-up, "
+          f"{out.compiles_served} while serving")
     if args.paged:
         pc = batcher.pcache
         print(f"  prefill tokens processed: {batcher.prefill_tokens}, "
@@ -213,8 +329,8 @@ def main():
         print(f"  prefix index: {ix.hits}/{ix.lookups} hits, "
               f"{ix.cached_tokens_served} prompt tokens served from cache, "
               f"{len(ix)} pages indexed")
-    for uid in sorted(results)[:3]:
-        print(f"  req {uid}: {results[uid]}")
+    for uid in sorted(out.results)[:3]:
+        print(f"  req {uid}: {out.results[uid]}")
     if telemetry is not None:
         lat = telemetry.latency_summary()
         print("  telemetry (DESIGN.md §13):")
@@ -230,7 +346,8 @@ def main():
         if telemetry.perf.phases:
             perf = telemetry.perf.summary()
             print("  perf attribution (DESIGN.md §14, "
-                  f"chip={perf['chip']}):")
+                  f"{perf['roofline_basis']} at chip={perf['chip']} "
+                  "peaks, not measured):")
             for phase, st in sorted(perf["phases"].items()):
                 print(f"    {phase}: {st['launches']} launches, "
                       f"predicted={st['predicted_bytes']}B "

@@ -21,6 +21,7 @@ from ..models import init_lm
 from ..models.encdec import init_encdec
 from ..optim import AdamWConfig
 from ..train import Trainer, TrainerConfig
+from .compile_cache import enable_compilation_cache
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(0)

@@ -295,7 +295,7 @@ def attention_decode_paged(
     params: Params,
     x: jnp.ndarray,             # [B, 1, D] — one new token per slot
     positions: jnp.ndarray,     # [B] int32 — per-slot index of the new token
-    k_pages: jnp.ndarray,       # [n_blocks, bs, KV, hd] shared page pool
+    k_pages: jnp.ndarray,       # [n_blocks, KV, bs, hd] shared page pool
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,   # [B, max_blocks] int32
     *,
@@ -338,21 +338,27 @@ def attention_decode_paged(
     With `k_scales=None` the float path is byte-for-byte the PR 8 code.
     """
     b = x.shape[0]
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
     q = apply_rope(q, positions[:, None], rope_theta)
     k = apply_rope(k, positions[:, None], rope_theta)
     page = block_table[jnp.arange(b), positions // bs]      # [B]
     offset = positions % bs
     if k_scales is None:
-        k_pages = k_pages.at[page, offset].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[page, offset].set(v[:, 0].astype(v_pages.dtype))
+        k_pages = k_pages.at[page, :, offset].set(
+            k[:, 0].astype(k_pages.dtype)
+        )
+        v_pages = v_pages.at[page, :, offset].set(
+            v[:, 0].astype(v_pages.dtype)
+        )
     else:
         rows = jnp.arange(b)
 
         def scatter_row(new):                      # new: [B, KV, hd]
-            def upd(pages_f):                      # [B, bs, KV, hd] f32
-                return pages_f.at[rows, offset].set(new.astype(jnp.float32))
+            def upd(pages_f):                      # [B, KV, bs, hd] f32
+                return pages_f.at[rows, :, offset].set(
+                    new.astype(jnp.float32)
+                )
             return upd
 
         k_codes, k_sc = requantize_page_update(
@@ -388,7 +394,7 @@ def attention_prefill_paged(
     x: jnp.ndarray,             # [B, T, D] — uncached suffix tokens (T padded)
     start: jnp.ndarray,         # [B] int32 — cached-prefix length per slot
     total: jnp.ndarray,         # [B] int32 — full valid length per slot
-    k_pages: jnp.ndarray,       # [n_blocks, bs, KV, hd] shared page pool
+    k_pages: jnp.ndarray,       # [n_blocks, KV, bs, hd] shared page pool
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,   # [B, max_blocks] int32
     *,
@@ -445,7 +451,7 @@ def attention_prefill_paged(
     the float path is byte-for-byte the PR 8 code.
     """
     b, t, _ = x.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     mb = block_table.shape[1]
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
     positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -460,8 +466,8 @@ def attention_prefill_paged(
     page = jnp.where(block_idx < mb, page, 0)
     offset = positions % bs
     if k_scales is None:
-        k_pages = k_pages.at[page, offset].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[page, offset].set(v.astype(v_pages.dtype))
+        k_pages = k_pages.at[page, :, offset].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[page, :, offset].set(v.astype(v_pages.dtype))
     else:
         valid = (positions < total[:, None]) & (block_idx < mb)  # [B, T]
         row = jnp.where(valid, block_idx, mb)          # pads → dummy row
@@ -471,11 +477,11 @@ def attention_prefill_paged(
         rows = jnp.arange(b)[:, None]
 
         def scatter_suffix(new):                   # new: [B, T, KV, hd]
-            def upd(pages_f):                      # [B, mb, bs, KV, hd] f32
+            def upd(pages_f):                      # [B, mb, KV, bs, hd] f32
                 padded = jnp.concatenate(
                     [pages_f, jnp.zeros_like(pages_f[:, :1])], axis=1
                 )
-                padded = padded.at[rows, row, offset].set(
+                padded = padded.at[rows, row, :, offset].set(
                     new.astype(jnp.float32)
                 )
                 return padded[:, :mb]

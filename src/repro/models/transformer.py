@@ -621,7 +621,9 @@ def layer_group_index(cfg: ModelConfig, capacity: int) -> np.ndarray:
 def init_paged_pool(
     cfg: ModelConfig, n_blocks: int, block_size: int, kv_dtype: str = "bf16"
 ) -> Tuple[jnp.ndarray, ...]:
-    """Per-layer KV page pools [L, n_blocks, bs, KV, hd]. Page-id SPACES
+    """Per-layer KV page pools [L, n_blocks, KV, bs, hd] (head-major
+    pages: each head's (bs, hd) plane is one tile-aligned slab, DESIGN.md
+    §10). Page-id SPACES
     are per layer group (DESIGN.md §12): layer l only ever reads pool[l]
     through its own group's block table, so two groups may hand out the
     same page index without aliasing — the stacked array is a physical
@@ -637,7 +639,7 @@ def init_paged_pool(
         raise ValueError(
             f"paged KV cache requires attention layers, got {cfg.block_kind}"
         )
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.hd)
+    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.hd)
     if kv_dtype == "bf16":
         dt = compute_dtype(cfg.dtype)
         return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
@@ -689,7 +691,7 @@ def _per_layer_paged_views(cfg, block_table, block_start, bucket_plan,
 def decode_step_paged(
     params: Params,
     token: jnp.ndarray,        # [B, 1] int32 — one token per slot
-    k_pages: jnp.ndarray,      # [L, n_blocks, bs, KV, hd]
+    k_pages: jnp.ndarray,      # [L, n_blocks, KV, bs, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [L, B, max_blocks] int32 per-layer tables
                                # (a [B, max_blocks] table broadcasts)
@@ -724,7 +726,7 @@ def decode_step_paged(
         raise ValueError("decode_step_paged supports attention stacks only")
     dt = compute_dtype(cfg.dtype)
     x = params["embed"][token].astype(dt)
-    capacity = block_table.shape[-1] * k_pages.shape[2]
+    capacity = block_table.shape[-1] * k_pages.shape[3]
     windows = _window_array(cfg, capacity)
     block_table, block_start, plans, perms, cls = _per_layer_paged_views(
         cfg, block_table, block_start, bucket_plan, bucket_perm, capacity
@@ -775,7 +777,7 @@ def decode_step_paged(
 def prefill_paged(
     params: Params,
     tokens: jnp.ndarray,       # [B, T] int32 — uncached suffix (T padded)
-    k_pages: jnp.ndarray,      # [L, n_blocks, bs, KV, hd]
+    k_pages: jnp.ndarray,      # [L, n_blocks, KV, bs, hd]
     v_pages: jnp.ndarray,
     block_table: jnp.ndarray,  # [L, B, max_blocks] int32 per-layer tables
                                # (a [B, max_blocks] table broadcasts)
@@ -815,7 +817,7 @@ def prefill_paged(
         raise ValueError("prefill_paged supports attention stacks only")
     dt = compute_dtype(cfg.dtype)
     x = _embed(params, tokens, cfg, None)
-    capacity = block_table.shape[-1] * k_pages.shape[2]
+    capacity = block_table.shape[-1] * k_pages.shape[3]
     windows = _window_array(cfg, capacity)
     block_table, block_start, plans, perms, cls = _per_layer_paged_views(
         cfg, block_table, block_start, bucket_plan, bucket_perm, capacity

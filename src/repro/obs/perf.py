@@ -269,12 +269,17 @@ class PerfModel:
     def summary(self) -> Dict[str, object]:
         """Per-phase attribution: predicted/measured/live/full bytes,
         exact max model error, roofline seconds at the device spec, and
-        each phase's fraction of the total predicted HBM time."""
+        each phase's fraction of the total predicted HBM time. Every
+        time here is `bytes / hbm_bandwidth` — a prediction, labelled as
+        such under `roofline_basis`; none is a device measurement."""
         bw = self.chip.hbm_bandwidth
         total_s = sum(
             st["measured_bytes"] / bw for st in self.phases.values()
         )
-        out: Dict[str, object] = {"chip": self.chip.name, "phases": {}}
+        out: Dict[str, object] = {
+            "chip": self.chip.name, "roofline_basis": "predicted",
+            "phases": {},
+        }
         for phase, st in sorted(self.phases.items()):
             meas = st["measured_bytes"]
             roofline_s = meas / bw

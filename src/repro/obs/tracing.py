@@ -29,7 +29,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence
 
-from ..core.tpu_gold import TPU_V5E, ChipSpec
+import jax
+
+from ..core.tpu_gold import ChipSpec, chip_for_device
 from ..kernels.ops import grouped_streamed_pages
 from .events import EventLog
 from .metrics import MetricsRegistry
@@ -111,8 +113,11 @@ class ServeTelemetry:
         #: request jax.profiler annotations (named_scope/TraceAnnotation)
         #: around the compiled step — read by the jit factories
         self.profile = profile
-        #: device spec the roofline predictions are priced against
-        self.chip = chip if chip is not None else TPU_V5E
+        #: device spec the roofline predictions are priced against: the
+        #: device's own (by `device_kind`), or v5e on a CPU run
+        self.chip = (
+            chip if chip is not None else chip_for_device(jax.devices()[0])
+        )
         #: predicted-vs-measured launch model (DESIGN.md §14)
         self.perf = PerfModel(registry, self.chip)
         self._compile_watcher: Optional[CompileWatcher] = None

@@ -40,9 +40,7 @@ def compressed_psum(
     Must run inside shard_map with `axis` unreduced. Returns (mean grads,
     new residual).
     """
-    from ..core.reduction import _axis_size
-
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, r):
         gf = g.astype(jnp.float32) + r

@@ -60,6 +60,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..kernels.paged_common import (
+    pages_to_tokens,
+    requantize_page_update,
+    tokens_to_pages,
+)
 from ..models import init_paged_pool, layer_attn_groups
 
 #: the reserved scratch page id (never allocated)
@@ -804,23 +809,20 @@ class PagedKVCache:
         lo = first * bs                      # page-aligned window start
         lead = start - lo
         pad = n_pages * bs - lead - n_tokens
-        _, _, kvh, hd = k.shape
         for p in self.pools:
             owned = p._owned[slot]
             pages = [owned[j] for j in range(first, first + n_pages)]
             assert all(pg is not None for pg in pages), (p.gid, slot)
             lg = jnp.asarray(p.layers)
             pages_j = jnp.asarray(np.array(pages, np.int32))
-            nl = len(p.layers)
             k_g = k[np.array(p.layers)]
             v_g = v[np.array(p.layers)]
 
             if self.quantized:
-                from ..kernels.paged_common import requantize_page_update
 
                 def rewrite(src):            # src: [nl, S, KV, hd]
-                    def upd(pages_f):        # [nl, n_pages, bs, KV, hd]
-                        flat = pages_f.reshape(nl, n_pages * bs, kvh, hd)
+                    def upd(pages_f):        # [nl, n_pages, KV, bs, hd]
+                        flat = pages_to_tokens(pages_f)
                         new = jnp.concatenate(
                             [flat[:, :lead],
                              src[:, :n_tokens].astype(jnp.float32)],
@@ -829,7 +831,7 @@ class PagedKVCache:
                         new = jnp.pad(
                             new, ((0, 0), (0, pad), (0, 0), (0, 0))
                         )
-                        return new.reshape(nl, n_pages, bs, kvh, hd)
+                        return tokens_to_pages(new, bs)
                     return upd
 
                 idx = (lg[:, None], pages_j[None, :])
@@ -851,9 +853,7 @@ class PagedKVCache:
                     [head.astype(src.dtype), src[:, :n_tokens]], axis=1
                 )
                 src = jnp.pad(src, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                src = src.reshape(nl, n_pages, bs, kvh, hd).astype(
-                    pool.dtype
-                )
+                src = tokens_to_pages(src, bs).astype(pool.dtype)
                 return pool.at[lg[:, None], pages_j[None, :]].set(src)
 
             # head rows live entirely in the window's first page
@@ -867,11 +867,7 @@ class PagedKVCache:
 
     def _gather_window(self, pool: jnp.ndarray, lg: jnp.ndarray,
                        pages: jnp.ndarray):
-        nl = lg.shape[0]
-        bs, kvh, hd = pool.shape[2], pool.shape[3], pool.shape[4]
-        return pool[lg[:, None], pages[None, :]].reshape(
-            nl, pages.shape[0] * bs, kvh, hd
-        )
+        return pages_to_tokens(pool[lg[:, None], pages[None, :]])
 
     # -- device views ------------------------------------------------------
 
@@ -1003,7 +999,7 @@ class PagedKVCache:
         predictions and `obs/tracing` measured launch accounting derive
         from this one number, which is what keeps the §14
         predicted-vs-measured gate at exactly zero on BOTH dtypes."""
-        _, _, bs, kvh, hd = self.k_pages.shape
+        _, _, kvh, bs, hd = self.k_pages.shape
         itemsize = jnp.dtype(self.k_pages.dtype).itemsize
         data = 2 * bs * kvh * hd * itemsize
         if self.quantized:

@@ -39,9 +39,7 @@ def test_jx001_host_callback_in_hot_path():
 
 
 def test_jx002_float64_creep():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0
         )(jnp.ones((4,), jnp.float32))

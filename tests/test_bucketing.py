@@ -176,8 +176,8 @@ def test_bucketed_bit_identical_to_single_launch(data):
     if plan is None:
         return
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = jnp.asarray(rng.integers(1, nb, size=(B, mb)), jnp.int32)
     lens_j = jnp.asarray(lengths, jnp.int32)
     win = jnp.asarray(window, jnp.int32)

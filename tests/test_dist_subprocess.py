@@ -32,8 +32,9 @@ def test_reduction_schedules_match_psum():
     (the paper's reduction networks, Table IV, as mesh collectives)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.reduction import SCHEDULES, make_sharded_allreduce
-        mesh = jax.make_mesh((8,), ("x",))
+        mesh = make_mesh((8,), ("x",))
         x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4) + 1
         ref = None
         for name in ("tree", "linear", "binary-hopping", "rs-ag"):
@@ -49,9 +50,10 @@ def test_reduction_schedules_match_psum():
 def test_reduce_to_zero_binary_hopping():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.core.reduction import reduce_to_zero_binary_hopping
-        mesh = jax.make_mesh((8,), ("x",))
+        mesh = make_mesh((8,), ("x",))
         x = (jnp.arange(8, dtype=jnp.float32) + 1).reshape(8, 1)
         f = jax.jit(jax.shard_map(
             lambda s: reduce_to_zero_binary_hopping(s, "x"),
@@ -68,9 +70,10 @@ def test_compressed_gradient_allreduce():
     the quantization error (error feedback property)."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import compressed_psum, init_residual
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         g = jnp.linspace(-1, 1, 8 * 64, dtype=jnp.float32).reshape(8, 64)
         grads = {"w": g}
         res = init_residual({"w": g})
@@ -95,6 +98,7 @@ def test_compressed_gradient_allreduce():
 def test_data_parallel_train_step_matches_single_device():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import init_lm
@@ -109,7 +113,7 @@ def test_data_parallel_train_step_matches_single_device():
         }
         step = make_train_step(cfg, AdamWConfig(lr=1e-3))
         p_ref, _, m_ref = jax.jit(step)(params, opt, batch)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         bsh = {k: NamedSharding(mesh, P("data")) for k in batch}
         psh = jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
         batch_sharded = jax.device_put(batch, bsh["tokens"])
@@ -126,6 +130,7 @@ def test_data_parallel_train_step_matches_single_device():
 def test_tensor_parallel_forward_matches():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.dist.sharding import sharding_rules
@@ -135,7 +140,7 @@ def test_tensor_parallel_forward_matches():
         params = init_lm(jax.random.PRNGKey(0), cfg)
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
         ref, _ = jax.jit(lambda p, t: forward(p, t, cfg))(params, toks)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, sharding_rules(mesh):
             psh = param_shardings(jax.eval_shape(lambda: params), mesh)
             f = jax.jit(lambda p, t: forward(p, t, cfg)[0], in_shardings=(psh, None))
@@ -151,15 +156,16 @@ def test_elastic_checkpoint_restore_across_meshes():
     """Save sharded on 8 devices, restore onto 4 and onto 1 (elasticity)."""
     out = run_py("""
         import tempfile, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt import CheckpointManager
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_mesh((8,), ("data",))
         w = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         sharded = jax.device_put(w, NamedSharding(mesh8, P("data")))
         with tempfile.TemporaryDirectory() as d:
             mgr = CheckpointManager(d)
             mgr.save(1, {"w": sharded})
-            mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+            mesh4 = make_mesh((4, 2), ("data", "model"))
             sh4 = {"w": NamedSharding(mesh4, P("model", "data"))}
             r4, _ = mgr.restore({"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}, shardings=sh4)
             np.testing.assert_array_equal(np.asarray(r4["w"]), np.asarray(w))

@@ -40,14 +40,22 @@ def test_pack_kernel_matches_ref(rng, n_bits, group):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n_bits,group", [(8, 1), (4, 1), (4, 2), (8, 2), (8, 4), (3, 1)])
-@pytest.mark.parametrize("B,K,M", [(2, 64, 128), (8, 128, 64)])
-def test_gemv_kernel_matches_oracle(rng, dtype, n_bits, group, B, K, M):
+@pytest.mark.parametrize("B,K,M,blocks", [
+    (2, 64, 128, (64, 4)),
+    (8, 128, 64, (64, 4)),
+    # the default preferred tiles (256, 128) divide neither M=384 nor, at
+    # group 1, K8=192: the kernel fits them (M tile 128, K8 whole)
+    (2, 1536, 384, (256, 128)),
+])
+def test_gemv_kernel_matches_oracle(rng, dtype, n_bits, group, B, K, M,
+                                    blocks):
     _, w_q, scale, planes = _quant_pack(rng, K, M, n_bits, group)
     x = jnp.asarray(rng.normal(size=(B, K)), dtype)
     y_ref = ref.bitplane_matmul_ref(x, planes, scale, n_bits, group)
     x_r = ref.prepare_x_ref(x, group)
     raw = bitplane_gemv(x_r, planes, n_bits=n_bits, group=group,
-                        block_m=64, block_k8=4, interpret=True)
+                        block_m=blocks[0], block_k8=blocks[1],
+                        interpret=True)
     off = float(2 ** (n_bits - 1))
     y = (raw - off * jnp.sum(x.astype(jnp.float32), -1, keepdims=True)) * scale[None]
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3

@@ -50,7 +50,7 @@ def test_roundtrip_half_step_bound(seed, scale_exp):
     """|dequant(quant(x)) - x| <= scale/2 elementwise, across magnitudes
     from subnormal-ish to large — the rounding step is the only loss."""
     rng = np.random.default_rng(seed)
-    pages = rng.normal(size=(3, 4, 2, 8)).astype(np.float32) * (
+    pages = rng.normal(size=(3, 2, 4, 8)).astype(np.float32) * (
         2.0 ** scale_exp
     )
     codes, scales = quantize_pages(jnp.asarray(pages))
@@ -58,7 +58,7 @@ def test_roundtrip_half_step_bound(seed, scale_exp):
     assert codes_np.dtype == np.int8
     assert codes_np.min() >= -128 and codes_np.max() <= 127
     deq = np.asarray(dequantize_pages(codes, scales))
-    half_step = np.asarray(scales)[:, None, :, None] / 2.0
+    half_step = np.asarray(scales)[:, :, None, None] / 2.0
     assert np.all(np.abs(deq - pages) <= half_step * (1 + 1e-5))
 
 
@@ -69,7 +69,7 @@ def test_requantize_identity_is_stable(seed):
     same codes/scales up to one rounding step — append drift is bounded,
     not cumulative blow-up."""
     rng = np.random.default_rng(seed)
-    pages = rng.normal(size=(2, 4, 2, 8)).astype(np.float32)
+    pages = rng.normal(size=(2, 2, 4, 8)).astype(np.float32)
     codes, scales = quantize_pages(jnp.asarray(pages))
     codes2, scales2 = requantize_page_update(codes, scales, lambda f: f)
     np.testing.assert_allclose(
@@ -86,7 +86,7 @@ def test_requantize_identity_is_stable(seed):
 def test_all_zero_pages_scale_guard():
     """All-zero planes take scale 1.0 (never 0): dequant is exactly
     zero and no division blows up anywhere in the round trip."""
-    codes, scales = quantize_pages(jnp.zeros((2, 4, 2, 8), jnp.float32))
+    codes, scales = quantize_pages(jnp.zeros((2, 2, 4, 8), jnp.float32))
     np.testing.assert_array_equal(np.asarray(scales), 1.0)
     np.testing.assert_array_equal(np.asarray(codes), 0)
     np.testing.assert_array_equal(
@@ -94,7 +94,7 @@ def test_all_zero_pages_scale_guard():
     )
     # an all-zero UPDATE of a live page drops the scale back to the guard
     live, live_s = quantize_pages(
-        jnp.ones((1, 4, 2, 8), jnp.float32) * 3.0
+        jnp.ones((1, 2, 4, 8), jnp.float32) * 3.0
     )
     z_codes, z_scales = requantize_page_update(
         live, live_s, lambda f: jnp.zeros_like(f)
@@ -106,22 +106,22 @@ def test_all_zero_pages_scale_guard():
 def test_negative_extreme_maps_to_minus_127():
     """Symmetric quantization: -absmax lands on code -127 (the -128
     slot is reachable only through crafted codes, not quantize)."""
-    pages = np.zeros((1, 4, 1, 4), np.float32)
+    pages = np.zeros((1, 1, 4, 4), np.float32)
     pages[0, 0, 0, 0] = -6.0
-    pages[0, 1, 0, 1] = 3.0
+    pages[0, 0, 1, 1] = 3.0
     codes, scales = quantize_pages(jnp.asarray(pages))
     assert float(np.asarray(scales)[0, 0]) == pytest.approx(6.0 / INT8_QMAX)
     assert int(np.asarray(codes)[0, 0, 0, 0]) == -127
     deq = np.asarray(dequantize_pages(codes, scales))
     assert deq[0, 0, 0, 0] == pytest.approx(-6.0)
-    assert deq[0, 1, 0, 1] == pytest.approx(3.0, rel=1e-2)
+    assert deq[0, 0, 1, 1] == pytest.approx(3.0, rel=1e-2)
 
 
 def test_full_negative_range_codes_survive_requantize():
     """Crafted -128 codes (full int8 range) requantize without overflow:
     the new absmax covers 128*scale, so the value is preserved exactly
     at code -127 under the widened scale."""
-    codes = jnp.full((1, 4, 2, 8), -128, jnp.int8)
+    codes = jnp.full((1, 2, 4, 8), -128, jnp.int8)
     scales = jnp.full((1, 2), 0.5, jnp.float32)
     want = np.asarray(dequantize_pages(codes, scales))  # all -64.0
     codes2, scales2 = requantize_page_update(codes, scales, lambda f: f)
@@ -141,7 +141,7 @@ def test_ragged_final_page_scale_from_valid_tokens(model_cfg):
                       kv_dtype="int8")
     assert pc.quantized
     L = cfg.n_layers
-    kvh, hd = pc.k_pages.shape[3], pc.k_pages.shape[4]
+    kvh, hd = pc.k_pages.shape[2], pc.k_pages.shape[4]
     n_tokens = 7                                  # 2 pages, ragged tail
     rng = np.random.default_rng(3)
     k = rng.normal(size=(L, n_tokens, kvh, hd)).astype(np.float32) * 0.1
@@ -161,7 +161,7 @@ def test_ragged_final_page_scale_from_valid_tokens(model_cfg):
     # and the stored rows round-trip within half a code step
     deq = np.asarray(dequantize_pages(
         pc.k_pages[lg, tail_page], pc.k_scales[lg, tail_page]
-    ))
+    )).swapaxes(0, 1)                             # token rows [bs, KV, hd]
     np.testing.assert_allclose(
         deq[: n_tokens - bs], tail_rows, rtol=0,
         atol=float(want_scale.max()) / 2 * 1.001,
@@ -178,7 +178,7 @@ def test_cow_then_append_content_stamp(model_cfg):
     pc = PagedKVCache(cfg, n_slots=2, max_len=16, block_size=4,
                       kv_dtype="int8")
     L = cfg.n_layers
-    kvh, hd = pc.k_pages.shape[3], pc.k_pages.shape[4]
+    kvh, hd = pc.k_pages.shape[2], pc.k_pages.shape[4]
     rng = np.random.default_rng(4)
     k = rng.normal(size=(L, 4, kvh, hd)).astype(np.float32)
     v = rng.normal(size=(L, 4, kvh, hd)).astype(np.float32)
@@ -208,7 +208,7 @@ def test_cow_then_append_content_stamp(model_cfg):
     # the COW'd page carries the old rows AND the appended token
     deq = np.asarray(dequantize_pages(
         pc.k_pages[0, new_page], pc.k_scales[0, new_page]
-    ))
+    )).swapaxes(0, 1)                             # token rows [bs, KV, hd]
     step = float(np.asarray(pc.k_scales)[0, new_page].max())
     np.testing.assert_allclose(
         deq[3], np.asarray(tok_k)[0, 0], rtol=0, atol=step * 1.001,
@@ -218,5 +218,6 @@ def test_cow_then_append_content_stamp(model_cfg):
         jnp.asarray(stamp_codes[0]), jnp.asarray(stamp_scales[0])
     )
     np.testing.assert_allclose(
-        deq[:3], np.asarray(old_deq)[:3], rtol=0, atol=2 * step,
+        deq[:3], np.asarray(old_deq).swapaxes(0, 1)[:3], rtol=0,
+        atol=2 * step,
     )

@@ -73,7 +73,7 @@ def _group_stamps(pc: PagedKVCache, gid: int, slot: int, positions):
     for p in positions:
         page = g._owned[slot][p // bs]
         assert page is not None, (gid, slot, p)
-        out.append(float(pool[layer, page, p % bs, 0, 0]) - layer * 1000)
+        out.append(float(pool[layer, page, 0, p % bs, 0]) - layer * 1000)
     return out
 
 
@@ -113,8 +113,8 @@ def test_decode_walk_start_bit_exact(rng):
     B, H, KV, hd, bs, nb, mb = 3, 4, 2, 8, 4, 24, 6
     W = 5
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = np.asarray(
         rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), np.int32
     )
@@ -160,8 +160,8 @@ def test_prefill_walk_start_bit_exact(rng):
     B, T, H, KV, hd, bs, nb, mb = 2, 4, 4, 2, 8, 4, 20, 6
     W = 5
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = np.asarray(
         rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), np.int32
     )

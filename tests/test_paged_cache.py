@@ -125,8 +125,8 @@ def test_reservations_gate_admission(model):
 def test_paged_kernel_matches_oracle(rng, window):
     B, H, KV, hd, bs, nb, mb = 3, 4, 2, 8, 4, 10, 3
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32
     )
@@ -143,8 +143,8 @@ def test_paged_oracle_matches_dense_softmax(rng):
     over the first `length` gathered positions (fp32 tolerance)."""
     B, H, KV, hd, bs, nb, mb = 2, 4, 2, 8, 4, 9, 2
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     lengths = [6, 3]
     out = ref.paged_attention_ref(
@@ -153,8 +153,8 @@ def test_paged_oracle_matches_dense_softmax(rng):
     )
     g = H // KV
     for bi, L in enumerate(lengths):
-        k = kp[bt[bi]].reshape(mb * bs, KV, hd)[:L]
-        v = vp[bt[bi]].reshape(mb * bs, KV, hd)[:L]
+        k = kp[bt[bi]].transpose(0, 2, 1, 3).reshape(mb * bs, KV, hd)[:L]
+        v = vp[bt[bi]].transpose(0, 2, 1, 3).reshape(mb * bs, KV, hd)[:L]
         qq = q[bi].reshape(KV, g, hd)
         sc = jnp.einsum("kgh,skh->kgs", qq, k) * hd ** -0.5
         dense = jnp.einsum(
@@ -299,8 +299,11 @@ def test_batcher_eos_stops_slot_and_frees_pages(model):
     for u, p in enumerate(prompts):
         cb0.submit(Request(uid=u, prompt=p, max_new_tokens=n_new))
     base = cb0.run_until_drained()
-    eos = base[0][3]  # request 0's 4th token
-    stop0 = base[0].index(eos)
+    # the EOS is a token request 0 first emits in DECODE: an EOS emitted
+    # at prefill frees slot 0, which the same tick re-seats with the next
+    # queued request, so slot 0's pages would not be request 0's
+    stop0 = next(i for i in range(1, n_new - 1) if base[0][i] not in base[0][:i])
+    eos = base[0][stop0]
 
     cb = ContinuousBatcher(
         cfg, params, n_slots=3, cache_len=32, paged=True, block_size=4,

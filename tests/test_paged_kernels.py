@@ -40,8 +40,8 @@ INT8_TOL = dict(rtol=5e-2, atol=5e-2)
 
 
 def _pools(rng, nb, bs, kv, hd, dtype=jnp.float32):
-    kp = jnp.asarray(rng.normal(size=(nb, bs, kv, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, kv, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(nb, kv, bs, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nb, kv, bs, hd)), dtype)
     return kp, vp
 
 
@@ -67,7 +67,7 @@ def _assert_bucketed_decode_matches_single(q, kp, vp, bt, lengths, window):
     single launch on every slot with length >= 1 (the cut tail pages
     fold as exact no-ops); length-0 rows are don't-care either way."""
     lens = np.asarray(lengths)
-    plan, perm = ops.make_bucket_plan(lens, kp.shape[1], bt.shape[1])
+    plan, perm = ops.make_bucket_plan(lens, kp.shape[2], bt.shape[1])
     if plan is None:  # degenerate plan: single launch IS the dispatch
         return
     single = paged_decode_attention(
@@ -88,7 +88,7 @@ def _assert_bucketed_prefill_matches_single(q, kp, vp, bt, start, total,
     the single launch bit-for-bit on every valid query row
     (start + t < total); padded rows are don't-care either way."""
     tot = np.asarray(total)
-    plan, perm = ops.make_bucket_plan(tot, kp.shape[1], bt.shape[1])
+    plan, perm = ops.make_bucket_plan(tot, kp.shape[2], bt.shape[1])
     if plan is None:
         return
     single = np.asarray(paged_prefill_attention(
@@ -171,16 +171,23 @@ def test_decode_cow_fragmented_tables(rng):
     )
 
 
-def test_decode_bf16_pool(rng):
+#: (query heads, head dim, page size): a small GQA geometry, and
+#: qwen2-1.5b's 12 query heads over 2 KV heads at head dim 128 on 16-token
+#: pages — six query rows per KV head in the head-major fold
+GEOMETRIES = [(4, 8, 4), (12, 128, 16)]
+
+
+@pytest.mark.parametrize("H,hd,bs", GEOMETRIES)
+def test_decode_bf16_pool(rng, H, hd, bs):
     """bf16 page pools (the serving default) load through the DMA scratch
     and fold in f32, exactly like the oracle."""
-    B, H, KV, hd, bs, nb, mb = 2, 4, 2, 8, 4, 6, 2
+    B, KV, nb, mb = 2, 2, 6, 2
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
     kp, vp = _pools(rng, nb, bs, KV, hd, jnp.bfloat16)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     _assert_decode_parity(
-        q, kp, vp, bt, jnp.asarray([6, 8], jnp.int32),
-        jnp.asarray(8, jnp.int32),
+        q, kp, vp, bt, jnp.asarray([bs + 2, 2 * bs], jnp.int32),
+        jnp.asarray(2 * bs, jnp.int32),
     )
 
 
@@ -212,8 +219,9 @@ def test_prefill_offsets_ragged_windows(rng, window, start, total):
     )
 
 
-def test_prefill_single_block_table(rng):
-    B, T, H, KV, hd, bs, nb = 2, 4, 4, 2, 8, 4, 5
+@pytest.mark.parametrize("H,hd,bs", GEOMETRIES)
+def test_prefill_single_block_table(rng, H, hd, bs):
+    B, T, KV, nb = 2, 4, 2, 5
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
     kp, vp = _pools(rng, nb, bs, KV, hd)
     bt = jnp.asarray([[2], [4]], jnp.int32)
@@ -279,10 +287,11 @@ def test_decode_int8_parity(rng, window, lengths):
     )
 
 
+@pytest.mark.parametrize("H,hd,bs", GEOMETRIES)
 @pytest.mark.parametrize("start,total", [([0, 0, 0], [6, 11, 4]),
                                          ([4, 8, 4], [11, 9, 12])])
-def test_prefill_int8_parity(rng, start, total):
-    B, T, H, KV, hd, bs, nb, mb = 3, 8, 4, 2, 8, 4, 10, 3
+def test_prefill_int8_parity(rng, start, total, H, hd, bs):
+    B, T, KV, nb, mb = 3, 8, 2, 10, 3
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
     kp, vp = _pools(rng, nb, bs, KV, hd)
     kq, ks = quantize_pages(kp)

@@ -62,7 +62,7 @@ def _slot_stamps(pc: PagedKVCache, slot: int, n: int) -> list:
     pool = np.asarray(pc.k_pages)
     bs = pc.block_size
     owned = pc.owned_blocks(slot)
-    return [float(pool[0, owned[p // bs], p % bs, 0, 0]) for p in range(n)]
+    return [float(pool[0, owned[p // bs], 0, p % bs, 0]) for p in range(n)]
 
 
 # ---------------------------------------------------------------------------
@@ -444,8 +444,8 @@ def test_refcount_zero_exactly_at_last_release(data):
 def test_paged_prefill_kernel_matches_oracle(rng, window, starts, totals):
     B, T, H, KV, hd, bs, nb, mb = 2, 8, 4, 2, 8, 4, 12, 4
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32
     )
@@ -464,8 +464,8 @@ def test_paged_prefill_oracle_matches_dense_softmax(rng):
     softmax attention over the first start+t+1 gathered positions."""
     B, T, H, KV, hd, bs, nb, mb = 1, 4, 4, 2, 8, 4, 9, 3
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, KV, bs, hd)), jnp.float32)
     bt = jnp.asarray([[2, 5, 7]], jnp.int32)
     start, total = 5, 9
     out = ref.paged_prefill_ref(
@@ -473,8 +473,8 @@ def test_paged_prefill_oracle_matches_dense_softmax(rng):
         jnp.asarray([total], jnp.int32), jnp.asarray(mb * bs, jnp.int32),
     )
     g = H // KV
-    k = kp[bt[0]].reshape(mb * bs, KV, hd)
-    v = vp[bt[0]].reshape(mb * bs, KV, hd)
+    k = kp[bt[0]].transpose(0, 2, 1, 3).reshape(mb * bs, KV, hd)
+    v = vp[bt[0]].transpose(0, 2, 1, 3).reshape(mb * bs, KV, hd)
     for t in range(total - start):
         L = start + t + 1
         qq = q[0, t].reshape(KV, g, hd)
