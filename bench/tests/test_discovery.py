@@ -1,0 +1,138 @@
+"""The harness finds configurations, traffic mixes and per-layer metrics
+by name from files of their own: a new mix and a new metric, added as
+new files beside copies of the existing ones, are picked up with no
+edit to any file that was there."""
+
+import json
+import os
+import shutil
+
+import smoke
+from bench import harness, peaks
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+DUMMY_METRIC = '''"""Requests still queued when the window closed (test metric)."""
+
+
+def read(ctx):
+    return float(len(ctx.rec.decode_batch))
+'''
+
+
+def _checkout(tmp_path):
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "bench"), root / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    return root, bench
+
+
+#: a new arrival shape as data alone: 4x bursts for 1 s in every 3 s,
+#: and a shared system prompt in front of half of the prompts
+BURST_MIX = {"arrivals": {
+    "phases": [{"seconds": 1.0, "rate_per_s": 8.0},
+               {"seconds": 2.0, "rate_per_s": 2.0}],
+    "ramp_s": 1.0, "prompt_len_pool": [3, 9, 20],
+    "output_len": {"median": 12, "sigma": 0.5, "min": 6, "max": 24},
+    "shared_prefix": {"len_pool": [32], "share": 0.5}}}
+
+DUMMY_E2E = '''"""Requests that finished inside the window (test metric)."""
+
+
+def read(ctx):
+    return float(sum(1 for tr in ctx.tracks
+                     if len(tr.times) == tr.spec.max_new_tokens))
+'''
+
+
+def test_new_mix_and_metric_files_are_picked_up(tmp_path):
+    root, bench = _checkout(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
+              if p.is_file()}
+    # new files only
+    (root / "bench" / "traffic" / "dummy_burst.json").write_text(
+        json.dumps(BURST_MIX))
+    (root / "bench" / "metrics" / "dummy_ticks.py").write_text(DUMMY_METRIC)
+    (root / "bench" / "metrics" / "dummy_done.py").write_text(DUMMY_E2E)
+    cell = json.loads((root / "bench" / "cells" /
+                       "qwen2-1.5b.chat.json").read_text())
+    cell["server"]["prefix"] = True
+    (root / "bench" / "cells" / "qwen2-1.5b.dummy_burst.json").write_text(
+        json.dumps(cell))
+    bench["workloads"].append({
+        "name": "qwen2-1.5b.dummy_burst", "config": "qwen2-1.5b",
+        "traffic": "dummy_burst", "chips": 1, "why": "test"})
+    bench["end_to_end"].append({
+        "name": "dummy_done", "unit": "count", "better": "higher",
+        "bound": 0.25, "source": "host_clock",
+        "workloads": ["qwen2-1.5b.dummy_burst"]})
+    bench["per_layer"].append({
+        "name": "dummy_ticks", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "serve.scheduler",
+        "moves": "dummy_done", "workloads": ["qwen2-1.5b.dummy_burst"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = harness.load_cell("qwen2-1.5b.dummy_burst", root=str(root))
+    assert cell.mix == BURST_MIX
+    assert [m["name"] for m in cell.per_layer][-1] == "dummy_ticks"
+    assert [m["name"] for m in cell.end_to_end][-1] == "dummy_done"
+    read = harness.load_reader("dummy_ticks", str(root / "bench"))
+
+    class Ctx:
+        rec = harness.Recorder()
+
+    Ctx.rec.decode_batch = [3, 4]
+    assert read(Ctx) == 2.0
+
+    # the whole run, at a small size, from the new files
+    cell.hf = smoke.model.smoke_config(cell.hf)
+    cell.server = dict(cell.server, **smoke.SMOKE_SERVER["qwen2-1.5b.chat"])
+    cell.check = dict(cell.check, max_logit_gap=smoke.SMOKE_LIMIT[
+        "qwen2-1.5b.chat"], **smoke.SMOKE_CHECK)
+    r = smoke.run(cell, 2**31 + 3, seconds=3.0)
+    assert r["correct"], r["checks"]
+    assert r["metrics"]["dummy_done"]["value"] > 0
+    assert r["attempted"] == 12          # 2 s at 2/s, then 1 s at 8/s
+    after = {p: p.read_bytes() for p in before}
+    assert after == before
+
+
+def test_traced_run_reports_the_new_metric(tmp_path, monkeypatch):
+    root, bench = _checkout(tmp_path)
+    (root / "bench" / "metrics" / "dummy_ticks.py").write_text(DUMMY_METRIC)
+    bench["per_layer"] = [{
+        "name": "dummy_ticks", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "serve.scheduler",
+        "moves": "output_tok_s"}]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = smoke.cell("qwen2-1.5b.chat")
+    found = harness.load_cell("qwen2-1.5b.chat", root=str(root))
+    cell.per_layer, cell.bench_dir = found.per_layer, found.bench_dir
+    # a CPU run has no published peaks; the test metric reads none
+    monkeypatch.setitem(peaks.PEAKS, "cpu", {})
+    r = smoke.run(cell, 5, seconds=2.0, trace=True)
+    assert r["metrics"]["dummy_ticks"]["value"] > 0
+    assert r["device"]["busy_s"] > 0 and r["device"]["window_s"] > 0
+    assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert r["correct"]
+
+
+def test_every_entry_of_the_benchmark_has_its_files():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    b = os.path.join(ROOT, "bench")
+    for c in bench["configs"]:
+        assert os.path.isfile(os.path.join(ROOT, c["file"]))
+    for w in bench["workloads"]:
+        for sub, name in (("cells", w["name"]), ("traffic", w["traffic"]),
+                          ("configs", w["config"])):
+            assert os.path.isfile(os.path.join(b, sub, name + ".json"))
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
+        assert callable(harness.load_reader(m["name"]))
+        moved = e2e[m["moves"]]
+        for w in m.get("workloads", []):
+            assert "workloads" not in moved or w in moved["workloads"]
