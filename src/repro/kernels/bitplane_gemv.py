@@ -115,4 +115,5 @@ def bitplane_gemv(
         out_specs=pl.BlockSpec((b, block_m), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         interpret=interpret,
+        name="bitplane_gemv",
     )(x_r, planes)

@@ -201,6 +201,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_table.astype(jnp.int32), block_start.astype(jnp.int32),
       lengths.astype(jnp.int32), win, q.reshape(b, n_kv, g, hd), *pools)
     return out.reshape(b, h, hd)

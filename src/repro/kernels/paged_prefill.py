@@ -237,6 +237,7 @@ def paged_prefill_attention(
             vmem_limit_bytes=VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_table.astype(jnp.int32), block_start.astype(jnp.int32),
       jnp.asarray(start, jnp.int32), jnp.asarray(total, jnp.int32), win,
       q_heads, *pools)

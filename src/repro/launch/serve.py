@@ -24,8 +24,12 @@ sliding-window groups retire pages that fall behind the window
 ``--arch gemma3-27b --paged`` for a mixed global/window stack).
 
 --warmup serves the same requests once before the timed drain, so the
-reported serving seconds exclude compilation. `run()` is the whole
-launch as a function; `chip_smoke.py` drives the chip through it.
+reported serving seconds exclude compilation. --trace-dir PATH records
+a `jax.profiler` trace of the timed drain there (one `.xplane.pb` under
+`PATH/plugins/profile/<run>/`): the scheduler's always-on `serve/*`
+spans (`obs.tracing.SPANS`) on the same clock as the device's ops.
+`run()` is the whole launch as a function; `chip_smoke.py` drives the
+chip through it.
 """
 
 from __future__ import annotations
@@ -126,9 +130,10 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap.add_argument("--events-out", default=None, metavar="PATH",
                     help="stream the structured JSON-lines event log to "
                          "PATH (implies --metrics)")
-    ap.add_argument("--profile-annotations", action="store_true",
-                    help="wrap compiled steps in jax.profiler trace "
-                         "annotations / named scopes (implies --metrics)")
+    ap.add_argument("--trace-dir", default=None, metavar="PATH",
+                    help="record a jax.profiler trace of the timed drain "
+                         "under PATH: the scheduler's serve/* tick-phase "
+                         "spans beside the device's ops")
     ap.add_argument("--warmup", action="store_true",
                     help="serve the same requests once, untimed, before "
                          "the timed drain: every step compiles there")
@@ -243,11 +248,8 @@ def run(args: argparse.Namespace) -> ServeRun:
     param_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
 
     telemetry = None
-    if args.metrics or args.events_out or args.profile_annotations:
-        telemetry = ServeTelemetry(
-            events_path=args.events_out,
-            profile=args.profile_annotations,
-        )
+    if args.metrics or args.events_out:
+        telemetry = ServeTelemetry(events_path=args.events_out)
 
     shared_len = args.shared_prefix_len if args.prefix else 0
     cache_len = shared_len + args.prompt_len + args.new_tokens + 8
@@ -274,13 +276,19 @@ def run(args: argparse.Namespace) -> ServeRun:
 
     for req in make_requests(args, cfg):
         batcher.submit(req)
+    if args.trace_dir:
+        jax.profiler.start_trace(args.trace_dir)
     t1 = time.perf_counter()
-    batcher.run_until_drained()
-    jax.block_until_ready(
-        (batcher.tokens, batcher.cache if batcher.pcache is None
-         else (batcher.pcache.k_pages, batcher.pcache.v_pages))
-    )
-    served_s = time.perf_counter() - t1
+    try:
+        batcher.run_until_drained()
+        jax.block_until_ready(
+            (batcher.tokens, batcher.cache if batcher.pcache is None
+             else (batcher.pcache.k_pages, batcher.pcache.v_pages))
+        )
+        served_s = time.perf_counter() - t1
+    finally:
+        if args.trace_dir:
+            jax.profiler.stop_trace()
     results = {u: batcher.finished[u] for u in range(args.requests)}
     return ServeRun(
         cfg=cfg, batcher=batcher, results=results, param_bytes=param_bytes,
@@ -331,6 +339,8 @@ def main(argv: Optional[List[str]] = None):
               f"{len(ix)} pages indexed")
     for uid in sorted(out.results)[:3]:
         print(f"  req {uid}: {out.results[uid]}")
+    if args.trace_dir:
+        print(f"  profiler trace of the timed drain under {args.trace_dir}")
     if telemetry is not None:
         lat = telemetry.latency_summary()
         print("  telemetry (DESIGN.md §13):")

@@ -27,7 +27,7 @@ from .perf import (
     predict_launch,
     predict_streamed_pages,
 )
-from .tracing import RequestTrace, ServeTelemetry
+from .tracing import SPANS, RequestTrace, ServeTelemetry
 
 __all__ = [
     "CompileWatcher",
@@ -43,6 +43,7 @@ __all__ = [
     "PerfModel",
     "RUN_END",
     "RequestTrace",
+    "SPANS",
     "ServeTelemetry",
     "exponential_buckets",
     "mutation_count",

@@ -39,6 +39,20 @@ from .perf import CompileWatcher, PerfModel
 
 _PCTS = (50.0, 90.0, 99.0)
 
+#: profiler spans `ContinuousBatcher.step()` writes on the
+#: `jax.profiler` timeline, always on (DESIGN.md §13): the tick, then
+#: its five phases (admission, prefill-chunk launch, first-token sync,
+#: decode launch, per-slot token sync), which sit side by side inside
+#: the tick and never overlap. Their metadata are host ints only.
+SPANS = (
+    "serve/tick",
+    "serve/admit",
+    "serve/prefill_launch",
+    "serve/first_token",
+    "serve/decode_launch",
+    "serve/token_sync",
+)
+
 
 @dataclasses.dataclass
 class RequestTrace:
@@ -101,7 +115,6 @@ class ServeTelemetry:
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  events: Optional[EventLog] = None, clock=None,
                  events_path: Optional[str] = None,
-                 profile: bool = False,
                  chip: Optional[ChipSpec] = None):
         if registry is None:
             registry = MetricsRegistry(clock=clock)
@@ -110,9 +123,6 @@ class ServeTelemetry:
         if events is None:
             events = EventLog(path=events_path, clock=self.clock)
         self.events = events
-        #: request jax.profiler annotations (named_scope/TraceAnnotation)
-        #: around the compiled step — read by the jit factories
-        self.profile = profile
         #: device spec the roofline predictions are priced against: the
         #: device's own (by `device_kind`), or v5e on a CPU run
         self.chip = (

@@ -21,15 +21,6 @@ bounds how many combinations can ever exist. `plans=None` (the default)
 is the everywhere-single-launch path and compiles exactly the PR-3
 program.
 
-`annotate=True` (DESIGN.md §13) adds profiler visibility at zero cost
-to the metrics-off path (it is a separate factory call, not a runtime
-branch): the traced program is wrapped in `jax.named_scope`, which tags
-every op's HLO metadata with the step name, and each compiled call runs
-under `jax.profiler.TraceAnnotation`, which brackets the host-side
-dispatch in the profiler timeline. Combined with the per-bucket
-`named_scope` in `kernels.paged_common.bucketed_page_dispatch`, a
-profile shows exactly which bucket launch streamed what.
-
 Compile-cache introspection (DESIGN.md §14): passing a `watcher` (an
 `obs.perf.CompileWatcher`) switches the factory to an ahead-of-time
 execution path. `_IntrospectedStep` keeps its own signature cache —
@@ -96,13 +87,10 @@ class _IntrospectedStep:
     argument structure it was lowered with (static `plans` is baked
     into it and must not be re-passed)."""
 
-    def __init__(self, kind: str, jitted, watcher, scope: str,
-                 annotate: bool):
+    def __init__(self, kind: str, jitted, watcher):
         self.kind = kind
         self._jitted = jitted
         self._watcher = watcher
-        self._scope = scope
-        self._annotate = annotate
         self._cache = {}
 
     def __call__(self, *args, **kwargs):
@@ -123,37 +111,19 @@ class _IntrospectedStep:
             walltime = self._watcher.clock() - t0
             self._cache[key] = compiled
             self._watcher.on_compile(self.kind, plans, walltime, compiled)
-        if self._annotate:
-            with jax.profiler.TraceAnnotation(self._scope):
-                return compiled(*args, **kwargs)
         return compiled(*args, **kwargs)
 
     def cache_size(self) -> int:
         return len(self._cache)
 
 
-def _annotated(jitted, scope: str):
-    """Wrap a compiled step so each dispatch lands in the profiler
-    timeline under `scope`. Keeps the jitted callable's signature
-    (positional + `perms`/`plans` keywords) intact."""
-
-    def wrapped(*args, **kwargs):
-        with jax.profiler.TraceAnnotation(scope):
-            return jitted(*args, **kwargs)
-
-    return wrapped
-
-
-def _finish(kind: str, jitted, scope: str, annotate: bool, watcher):
+def _finish(kind: str, jitted, watcher):
     if watcher is not None:
-        return _IntrospectedStep(kind, jitted, watcher, scope, annotate)
-    if annotate:
-        return _annotated(jitted, scope)
+        return _IntrospectedStep(kind, jitted, watcher)
     return jitted
 
 
-def jit_paged_prefill(cfg: ModelConfig, impl: str = "auto",
-                      annotate: bool = False, watcher=None,
+def jit_paged_prefill(cfg: ModelConfig, impl: str = "auto", watcher=None,
                       kv_dtype: str = "bf16"):
     """(params, toks, k_pages, v_pages, block_tables, block_starts,
     start, total, last_pos[, perms], plans=...) ->
@@ -179,13 +149,6 @@ def jit_paged_prefill(cfg: ModelConfig, impl: str = "auto",
         def qfn(p, toks, kp, vp, ks, vs, bt, st, strt, tot, lp,
                 perms=None, plans=None):
             _note_trace("prefill", plans)
-            if annotate:
-                with jax.named_scope("serve/paged_prefill"):
-                    return prefill_paged(
-                        p, toks, kp, vp, bt, strt, tot, cfg, last_pos=lp,
-                        impl=impl, bucket_plan=plans, bucket_perm=perms,
-                        block_start=st, k_scales=ks, v_scales=vs,
-                    )
             return prefill_paged(
                 p, toks, kp, vp, bt, strt, tot, cfg, last_pos=lp,
                 impl=impl, bucket_plan=plans, bucket_perm=perms,
@@ -193,30 +156,20 @@ def jit_paged_prefill(cfg: ModelConfig, impl: str = "auto",
             )
 
         jitted = jax.jit(qfn, static_argnames=("plans",))
-        return _finish("prefill", jitted, "serve/paged_prefill", annotate,
-                       watcher)
+        return _finish("prefill", jitted, watcher)
 
     def fn(p, toks, kp, vp, bt, st, strt, tot, lp, perms=None, plans=None):
         _note_trace("prefill", plans)
-        if annotate:
-            with jax.named_scope("serve/paged_prefill"):
-                return prefill_paged(
-                    p, toks, kp, vp, bt, strt, tot, cfg, last_pos=lp,
-                    impl=impl, bucket_plan=plans, bucket_perm=perms,
-                    block_start=st,
-                )
         return prefill_paged(
             p, toks, kp, vp, bt, strt, tot, cfg, last_pos=lp, impl=impl,
             bucket_plan=plans, bucket_perm=perms, block_start=st,
         )
 
     jitted = jax.jit(fn, static_argnames=("plans",))
-    return _finish("prefill", jitted, "serve/paged_prefill", annotate,
-                   watcher)
+    return _finish("prefill", jitted, watcher)
 
 
-def jit_paged_decode(cfg: ModelConfig, impl: str = "auto",
-                     annotate: bool = False, watcher=None,
+def jit_paged_decode(cfg: ModelConfig, impl: str = "auto", watcher=None,
                      kv_dtype: str = "bf16"):
     """(params, token, k_pages, v_pages, block_tables, block_starts,
     positions[, perms], plans=...) -> (logits, k_pages, v_pages).
@@ -231,13 +184,6 @@ def jit_paged_decode(cfg: ModelConfig, impl: str = "auto",
     if kv_dtype == "int8":
         def qfn(p, t, kp, vp, ks, vs, bt, st, pos, perms=None, plans=None):
             _note_trace("decode", plans)
-            if annotate:
-                with jax.named_scope("serve/paged_decode"):
-                    return decode_step_paged(
-                        p, t, kp, vp, bt, pos, cfg, impl=impl,
-                        bucket_plan=plans, bucket_perm=perms,
-                        block_start=st, k_scales=ks, v_scales=vs,
-                    )
             return decode_step_paged(
                 p, t, kp, vp, bt, pos, cfg, impl=impl,
                 bucket_plan=plans, bucket_perm=perms, block_start=st,
@@ -245,59 +191,41 @@ def jit_paged_decode(cfg: ModelConfig, impl: str = "auto",
             )
 
         jitted = jax.jit(qfn, static_argnames=("plans",))
-        return _finish("decode", jitted, "serve/paged_decode", annotate,
-                       watcher)
+        return _finish("decode", jitted, watcher)
 
     def fn(p, t, kp, vp, bt, st, pos, perms=None, plans=None):
         _note_trace("decode", plans)
-        if annotate:
-            with jax.named_scope("serve/paged_decode"):
-                return decode_step_paged(
-                    p, t, kp, vp, bt, pos, cfg, impl=impl,
-                    bucket_plan=plans, bucket_perm=perms, block_start=st,
-                )
         return decode_step_paged(
             p, t, kp, vp, bt, pos, cfg, impl=impl,
             bucket_plan=plans, bucket_perm=perms, block_start=st,
         )
 
     jitted = jax.jit(fn, static_argnames=("plans",))
-    return _finish("decode", jitted, "serve/paged_decode", annotate,
-                   watcher)
+    return _finish("decode", jitted, watcher)
 
 
-def jit_dense_prefill(cfg: ModelConfig, cache_len: int,
-                      annotate: bool = False, watcher=None):
+def jit_dense_prefill(cfg: ModelConfig, cache_len: int, watcher=None):
     """(params, toks) -> (logits, cache): the dense pre-allocated-cache
     prefill. Lives here (not inline in the engines) so dense serve-step
-    compiles share the paged path's introspection/annotation plumbing —
+    compiles share the paged path's introspection plumbing —
     `jax.jit` of a serve step outside this module is a lint violation
     (analysis rule RL201)."""
 
     def fn(p, toks, plans=None):
         _note_trace("dense_prefill", plans)
-        if annotate:
-            with jax.named_scope("serve/dense_prefill"):
-                return prefill(p, toks, cfg, cache_len=cache_len)
         return prefill(p, toks, cfg, cache_len=cache_len)
 
     jitted = jax.jit(fn, static_argnames=("plans",))
-    return _finish("dense_prefill", jitted, "serve/dense_prefill",
-                   annotate, watcher)
+    return _finish("dense_prefill", jitted, watcher)
 
 
-def jit_dense_decode(cfg: ModelConfig, annotate: bool = False,
-                     watcher=None):
+def jit_dense_decode(cfg: ModelConfig, watcher=None):
     """(params, token, cache) -> (logits, cache): one dense decode
     step. Same single-home rule as `jit_dense_prefill`."""
 
     def fn(p, t, cache, plans=None):
         _note_trace("dense_decode", plans)
-        if annotate:
-            with jax.named_scope("serve/dense_decode"):
-                return decode_step(p, t, cache, cfg)
         return decode_step(p, t, cache, cfg)
 
     jitted = jax.jit(fn, static_argnames=("plans",))
-    return _finish("dense_decode", jitted, "serve/dense_decode",
-                   annotate, watcher)
+    return _finish("dense_decode", jitted, watcher)

@@ -92,22 +92,18 @@ class ServeEngine:
         #: monotone uid base so rows of successive generate() calls get
         #: distinct trace uids
         self._uid_base = 0
-        annotate = telemetry is not None and telemetry.profile
         watcher = None if telemetry is None else telemetry.compile_watcher()
         self._prefill = jit_dense_prefill(
-            cfg, serve_cfg.max_cache_len, annotate=annotate,
-            watcher=watcher,
+            cfg, serve_cfg.max_cache_len, watcher=watcher
         )
-        self._decode = jit_dense_decode(
-            cfg, annotate=annotate, watcher=watcher
-        )
+        self._decode = jit_dense_decode(cfg, watcher=watcher)
         self._decode_paged = jit_paged_decode(
-            cfg, impl=serve_cfg.kernel_impl, annotate=annotate,
-            watcher=watcher, kv_dtype=serve_cfg.kv_dtype,
+            cfg, impl=serve_cfg.kernel_impl, watcher=watcher,
+            kv_dtype=serve_cfg.kv_dtype,
         )
         self._prefill_paged = jit_paged_prefill(
-            cfg, impl=serve_cfg.kernel_impl, annotate=annotate,
-            watcher=watcher, kv_dtype=serve_cfg.kv_dtype,
+            cfg, impl=serve_cfg.kernel_impl, watcher=watcher,
+            kv_dtype=serve_cfg.kv_dtype,
         )
         if serve_cfg.kv_dtype != "bf16" and not serve_cfg.paged:
             raise ValueError("kv_dtype='int8' requires paged=True")

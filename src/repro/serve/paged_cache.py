@@ -113,6 +113,11 @@ class LayerPagePool:
         ]
         #: refcount per allocated (non-free) page
         self._ref: Dict[int, int] = {}
+        #: sum(refcount - 1) and the count of pages with refcount > 1,
+        #: kept at every refcount change so the per-tick gauges never
+        #: walk `_ref` (`check_invariants` recomputes both)
+        self._extra_refs = 0
+        self._shared_pages = 0
         #: admission control: draws promised (reserve) vs made (_drawn)
         self._reserved: Dict[int, int] = {}
         self._drawn: Dict[int, int] = collections.defaultdict(int)
@@ -141,7 +146,10 @@ class LayerPagePool:
         return len(self._ref)
 
     def extra_refs(self) -> int:
-        return sum(r - 1 for r in self._ref.values())
+        return self._extra_refs
+
+    def shared_pages(self) -> int:
+        return self._shared_pages
 
     def available_blocks(self) -> int:
         outstanding = sum(
@@ -167,7 +175,10 @@ class LayerPagePool:
 
     def retain(self, page: int) -> None:
         assert page in self._ref, (self.gid, page)
-        self._ref[page] += 1
+        r = self._ref[page]
+        self._ref[page] = r + 1
+        self._extra_refs += 1
+        self._shared_pages += r == 1
 
     def release(self, page: int) -> bool:
         """Drop one reference; recycle at zero (LIFO — just-released
@@ -176,6 +187,8 @@ class LayerPagePool:
         r = self._ref[page] - 1
         if r:
             self._ref[page] = r
+            self._extra_refs -= 1
+            self._shared_pages -= r == 1
             return False
         del self._ref[page]
         self.free_blocks.appendleft(page)
@@ -260,8 +273,8 @@ class LayerPagePool:
             return
         owned: List[Optional[int]] = [None] * j0
         for i, p in enumerate(pages):
-            assert p != SCRATCH_PAGE and p in self._ref, (self.gid, p)
-            self._ref[p] += 1
+            assert p != SCRATCH_PAGE, (self.gid, p)
+            self.retain(p)
             self.block_table[slot, j0 + i] = p
             owned.append(p)
         self._owned[slot] = owned
@@ -302,7 +315,7 @@ class LayerPagePool:
             cache.v_scales = cache.v_scales.at[lg, new].set(
                 cache.v_scales[lg, old]
             )
-        self._ref[old] -= 1
+        self.release(old)             # refcount > 1: stays allocated
         self._owned[slot][block_idx] = new
         self.block_table[slot, block_idx] = new
         self.cow_events += 1
@@ -340,6 +353,11 @@ class LayerPagePool:
                     (self.gid, p, r, held)
         for p in slot_holds:
             assert p in self._ref, (self.gid, p)
+        assert self._extra_refs == sum(r - 1 for r in self._ref.values()), \
+            f"group {self.gid}: extra-refs counter drifted"
+        assert self._shared_pages == sum(
+            1 for r in self._ref.values() if r > 1
+        ), f"group {self.gid}: shared-pages counter drifted"
         assert self.available_blocks() >= 0, \
             f"group {self.gid}: over-committed reservations"
         # the §17 ledger invariant: a reserved slot's net draws (draws
@@ -1083,9 +1101,7 @@ class PagedKVCache:
         plb = self.page_layer_bytes
         n_layers = self.k_pages.shape[0]
         allocated = sum(p.allocated_pages() for p in self.pools)
-        shared = sum(
-            sum(1 for r in p._ref.values() if r > 1) for p in self.pools
-        )
+        shared = sum(p.shared_pages() for p in self.pools)
         extra = sum(p.extra_refs() for p in self.pools)
         phys = sum(
             len(p.layers) * p.allocated_pages() for p in self.pools

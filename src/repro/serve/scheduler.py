@@ -60,11 +60,12 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ModelConfig
 from ..kernels.ops import bucket_args_grouped, resolve_bucket_strategy
 from ..models import init_cache
-from ..obs import ServeTelemetry
+from ..obs import SPANS, ServeTelemetry
 from .compiled import (
     jit_dense_decode,
     jit_dense_prefill,
@@ -73,6 +74,9 @@ from .compiled import (
 )
 from .paged_cache import PagedKVCache
 from .prefix_cache import PrefixIndex
+
+_TICK, _ADMIT, _PREFILL_LAUNCH, _FIRST_TOKEN, _DECODE_LAUNCH, _TOKEN_SYNC = \
+    SPANS
 
 
 @dataclasses.dataclass
@@ -186,7 +190,6 @@ class ContinuousBatcher:
             )
             if prefix else None
         )
-        annotate = telemetry is not None and telemetry.profile
         # telemetry attached => compile-cache introspection on: every
         # XLA compile of a serve step is observed (DESIGN.md §14)
         watcher = None if telemetry is None else telemetry.compile_watcher()
@@ -200,15 +203,13 @@ class ContinuousBatcher:
             )
             self.cache = None
             self._decode_paged = jit_paged_decode(
-                cfg, impl=kernel_impl, annotate=annotate, watcher=watcher,
-                kv_dtype=kv_dtype,
+                cfg, impl=kernel_impl, watcher=watcher, kv_dtype=kv_dtype,
             )
             # suffixes are right-padded to a block-size multiple, so this
             # retraces once per bucket and `last_pos` selects the true
             # suffix end dynamically
             self._prefill_paged = jit_paged_prefill(
-                cfg, impl=kernel_impl, annotate=annotate, watcher=watcher,
-                kv_dtype=kv_dtype,
+                cfg, impl=kernel_impl, watcher=watcher, kv_dtype=kv_dtype,
             )
             #: chunked prefill (DESIGN.md §17): a prompt whose uncached
             #: suffix exceeds this many tokens prefills in block-multiple
@@ -218,11 +219,9 @@ class ContinuousBatcher:
         else:
             self.pcache = None
             self.cache = init_cache(cfg, n_slots, cache_len)
-            self._decode = jit_dense_decode(
-                cfg, annotate=annotate, watcher=watcher
-            )
+            self._decode = jit_dense_decode(cfg, watcher=watcher)
             self._prefill_dense = jit_dense_prefill(
-                cfg, cache_len, annotate=annotate, watcher=watcher
+                cfg, cache_len, watcher=watcher
             )
             self.prefill_chunk = 0
         #: slot -> next un-prefilled prompt position of an in-flight
@@ -248,7 +247,9 @@ class ContinuousBatcher:
             # this very tick instead of idling the slot for a whole tick
             while self.slots[i] is None and self.queue:
                 if self.paged:
-                    admitted = self._admit_paged(i)
+                    with TraceAnnotation(_ADMIT, slot=i,
+                                         queued=len(self.queue)):
+                        admitted = self._admit_paged(i)
                     if admitted is None:
                         # nothing in the queue fits right now; later slots
                         # see the same pool, so stop scanning this tick
@@ -333,9 +334,12 @@ class ContinuousBatcher:
     def _prefill_into_dense(self, i: int, req: Request):
         if self.telemetry is not None:
             self.telemetry.on_admit(req.uid, i)
-        logits, c1 = self._prefill_dense(self.params, req.prompt[None, :])
-        self.cache = _insert_batch(self.cache, c1, i)
         t = int(req.prompt.shape[0])
+        with TraceAnnotation(_PREFILL_LAUNCH, uid=req.uid, slot=i,
+                             start=0, end=t):
+            logits, c1 = self._prefill_dense(self.params,
+                                             req.prompt[None, :])
+            self.cache = _insert_batch(self.cache, c1, i)
         self.prefill_tokens += t
         if self.telemetry is not None:
             self.telemetry.on_prefill(req.uid, t)
@@ -386,54 +390,59 @@ class ContinuousBatcher:
         multiple, so the compile set stays bounded by the §11 pow2 plan
         machinery: mid chunks are always exactly `prefill_chunk` wide
         and only the tail chunk is ragged."""
-        pc = self.pcache
-        bs = pc.block_size
-        n = end - start
-        pad = -(-n // bs) * bs
-        # host-side page prep BEFORE the device table snapshot: retire
-        # blocks behind the chunk's window, grow capacity for the chunk,
-        # COW any shared page the scatter touches
-        pc.begin_append(i, start, n)
-        toks = jnp.pad(req.prompt[start:end], (0, pad - n))[None, :]
-        # bucket the one-slot launch by the slot's LIVE page occupancy
-        # per layer group so the prefill walk stops at the bucket bound
-        # instead of streaming the slot's whole max_blocks-deep table
-        plans, perms = self._bucket_args([end], slots=[i])
-        bt, st = pc.device_block_tables(), pc.device_block_starts()
-        if bt.ndim == 2:                 # single group: [B, mb] / [B]
-            bt, st = bt[i: i + 1], st[i: i + 1]
-        else:                            # layer-major: [L, B, mb] / [L, B]
-            bt, st = bt[:, i: i + 1], st[:, i: i + 1]
-        if pc.quantized:
-            (logits, pc.k_pages, pc.v_pages,
-             pc.k_scales, pc.v_scales) = self._prefill_paged(
-                self.params, toks, pc.k_pages, pc.v_pages,
-                pc.k_scales, pc.v_scales, bt, st,
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray([end], jnp.int32),
-                jnp.asarray(n - 1, jnp.int32), perms, plans=plans,
-            )
-        else:
-            logits, pc.k_pages, pc.v_pages = self._prefill_paged(
-                self.params, toks, pc.k_pages, pc.v_pages, bt, st,
-                jnp.asarray([start], jnp.int32), jnp.asarray([end], jnp.int32),
-                jnp.asarray(n - 1, jnp.int32), perms, plans=plans,
-            )
-        pc.lengths[i] = end
-        self.prefill_tokens += pad
-        if self.telemetry is not None:
-            self.telemetry.on_prefill(req.uid, pad)
-            # one-slot launch: n_rows=1 (the table snapshot was sliced);
-            # geometry inputs let the perf model re-predict the launch —
-            # per-chunk accounting right after the chunk's begin_append
-            # reads the same live pool state the plan was built from, so
-            # the §14 predicted-vs-measured gate stays at exactly 0
-            self.telemetry.account_paged_launch(
-                "prefill", plans, 1, pc, eff_lengths=[end], slots=[i],
-                strategy=self.bucket_strategy,
-                kernel_impl=self._kernel_impl,
-            )
-        return logits
+        with TraceAnnotation(_PREFILL_LAUNCH, uid=req.uid, slot=i,
+                             start=start, end=end):
+            pc = self.pcache
+            bs = pc.block_size
+            n = end - start
+            pad = -(-n // bs) * bs
+            # host-side page prep BEFORE the device table snapshot:
+            # retire blocks behind the chunk's window, grow capacity for
+            # the chunk, COW any shared page the scatter touches
+            pc.begin_append(i, start, n)
+            toks = jnp.pad(req.prompt[start:end], (0, pad - n))[None, :]
+            # bucket the one-slot launch by the slot's LIVE page
+            # occupancy per layer group so the prefill walk stops at the
+            # bucket bound instead of streaming the slot's whole
+            # max_blocks-deep table
+            plans, perms = self._bucket_args([end], slots=[i])
+            bt, st = pc.device_block_tables(), pc.device_block_starts()
+            if bt.ndim == 2:               # single group: [B, mb] / [B]
+                bt, st = bt[i: i + 1], st[i: i + 1]
+            else:                          # layer-major: [L, B, mb] / [L, B]
+                bt, st = bt[:, i: i + 1], st[:, i: i + 1]
+            if pc.quantized:
+                (logits, pc.k_pages, pc.v_pages,
+                 pc.k_scales, pc.v_scales) = self._prefill_paged(
+                    self.params, toks, pc.k_pages, pc.v_pages,
+                    pc.k_scales, pc.v_scales, bt, st,
+                    jnp.asarray([start], jnp.int32),
+                    jnp.asarray([end], jnp.int32),
+                    jnp.asarray(n - 1, jnp.int32), perms, plans=plans,
+                )
+            else:
+                logits, pc.k_pages, pc.v_pages = self._prefill_paged(
+                    self.params, toks, pc.k_pages, pc.v_pages, bt, st,
+                    jnp.asarray([start], jnp.int32),
+                    jnp.asarray([end], jnp.int32),
+                    jnp.asarray(n - 1, jnp.int32), perms, plans=plans,
+                )
+            pc.lengths[i] = end
+            self.prefill_tokens += pad
+            if self.telemetry is not None:
+                self.telemetry.on_prefill(req.uid, pad)
+                # one-slot launch: n_rows=1 (the table snapshot was
+                # sliced); geometry inputs let the perf model re-predict
+                # the launch — per-chunk accounting right after the
+                # chunk's begin_append reads the same live pool state the
+                # plan was built from, so the §14 predicted-vs-measured
+                # gate stays at exactly 0
+                self.telemetry.account_paged_launch(
+                    "prefill", plans, 1, pc, eff_lengths=[end], slots=[i],
+                    strategy=self.bucket_strategy,
+                    kernel_impl=self._kernel_impl,
+                )
+            return logits
 
     def _finish_prefill(self, i: int, req: Request, logits):
         """Post-prefill bookkeeping once the FULL prompt's KV is in the
@@ -470,23 +479,24 @@ class ContinuousBatcher:
         return self.eos_token >= 0 and tok == self.eos_token
 
     def _start_slot(self, i: int, req: Request, logits):
-        nxt = int(jnp.argmax(logits[0, -1]))
-        req.generated.append(nxt)
-        if self.telemetry is not None:
-            self.telemetry.on_first_token(req.uid)
-        if req.done or self._hit_eos(nxt):
-            # the prefill token completes the request (max_new_tokens == 1,
-            # or the prompt's continuation is EOS) — entering decode would
-            # emit an extra token (and write KV past the slot's
-            # reservation); pages free immediately
-            self.finished[req.uid] = req.generated
-            if self.paged:
-                self.pcache.free_slot(i)
+        with TraceAnnotation(_FIRST_TOKEN, uid=req.uid, slot=i):
+            nxt = int(jnp.argmax(logits[0, -1]))
+            req.generated.append(nxt)
             if self.telemetry is not None:
-                self.telemetry.on_finish(req.uid)
-            return
-        self.tokens = self.tokens.at[i, 0].set(nxt)
-        self.slots[i] = req
+                self.telemetry.on_first_token(req.uid)
+            if req.done or self._hit_eos(nxt):
+                # the prefill token completes the request (max_new_tokens
+                # == 1, or the prompt's continuation is EOS) — entering
+                # decode would emit an extra token (and write KV past the
+                # slot's reservation); pages free immediately
+                self.finished[req.uid] = req.generated
+                if self.paged:
+                    self.pcache.free_slot(i)
+                if self.telemetry is not None:
+                    self.telemetry.on_finish(req.uid)
+                return
+            self.tokens = self.tokens.at[i, 0].set(nxt)
+            self.slots[i] = req
 
     # -- decode ------------------------------------------------------------
 
@@ -495,48 +505,53 @@ class ContinuousBatcher:
         chunk each, fill free slots, decode once. Returns the number of
         slots advanced (decode + chunk progress — both count for the
         drain loop's liveness check)."""
-        n_finished = len(self.finished)
-        # chunks first: a finishing final chunk may free its slot (done
-        # at prefill) for this very tick's admission pass below
-        chunked = self._advance_chunked() if self._chunk_pos else 0
-        self._fill_slots()
-        active = [
-            i for i, s in enumerate(self.slots)
-            if s is not None and i not in self._chunk_pos
-        ]
-        if not active:
-            if chunked or len(self.finished) > n_finished:
-                # prefill-only tick: chunk progress, or every admitted
-                # request completed AT prefill — real work, count it
-                self.ticks += 1
+        with TraceAnnotation(_TICK, tick=self.ticks):
+            n_finished = len(self.finished)
+            # chunks first: a finishing final chunk may free its slot (done
+            # at prefill) for this very tick's admission pass below
+            chunked = self._advance_chunked() if self._chunk_pos else 0
+            self._fill_slots()
+            active = [
+                i for i, s in enumerate(self.slots)
+                if s is not None and i not in self._chunk_pos
+            ]
+            if not active:
+                if chunked or len(self.finished) > n_finished:
+                    # prefill-only tick: chunk progress, or every admitted
+                    # request completed AT prefill — real work, count it
+                    self.ticks += 1
+                if self.telemetry is not None:
+                    self._sample_tick()
+                return chunked
+            with TraceAnnotation(_DECODE_LAUNCH, active=len(active)):
+                if self.paged:
+                    nxt = self._step_paged(active)
+                else:
+                    logits, self.cache = self._decode(self.params, self.tokens,
+                                                      self.cache)
+                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            if self.telemetry is not None:
+                self.telemetry.on_decode([self.slots[i].uid for i in active])
+            with TraceAnnotation(_TOKEN_SYNC, active=len(active)):
+                for i in active:
+                    req = self.slots[i]
+                    tok = int(nxt[i])
+                    req.generated.append(tok)
+                    if req.done or self._hit_eos(tok):
+                        # finished by budget or by EOS: the slot's pages
+                        # recycle this very tick, before the next
+                        # _fill_slots admission
+                        self.finished[req.uid] = req.generated
+                        if self.paged:
+                            self.pcache.free_slot(i)
+                        self.slots[i] = None
+                        if self.telemetry is not None:
+                            self.telemetry.on_finish(req.uid)
+                self.tokens = nxt[:, None]
+            self.ticks += 1
             if self.telemetry is not None:
                 self._sample_tick()
-            return chunked
-        if self.paged:
-            nxt = self._step_paged(active)
-        else:
-            logits, self.cache = self._decode(self.params, self.tokens, self.cache)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        if self.telemetry is not None:
-            self.telemetry.on_decode([self.slots[i].uid for i in active])
-        for i in active:
-            req = self.slots[i]
-            tok = int(nxt[i])
-            req.generated.append(tok)
-            if req.done or self._hit_eos(tok):
-                # finished by budget or by EOS: the slot's pages recycle
-                # this very tick, before the next _fill_slots admission
-                self.finished[req.uid] = req.generated
-                if self.paged:
-                    self.pcache.free_slot(i)
-                self.slots[i] = None
-                if self.telemetry is not None:
-                    self.telemetry.on_finish(req.uid)
-        self.tokens = nxt[:, None]
-        self.ticks += 1
-        if self.telemetry is not None:
-            self._sample_tick()
-        return len(active) + chunked
+            return len(active) + chunked
 
     def _sample_tick(self):
         """End-of-tick gauge sample (telemetry attached only): queue

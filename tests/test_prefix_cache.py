@@ -258,6 +258,54 @@ def test_cross_layer_dedup_stats_count_shared_columns():
     assert pc.cross_layer_dedup_stats()["allocated_pages"] == 0
 
 
+def test_gauge_counters_follow_every_refcount_change():
+    """The pool keeps `sum(refcount - 1)` and the count of pages held
+    more than once as counters, so the per-tick gauges never walk the
+    refcounts: each refcount change (draw, attach, retain, release,
+    copy-on-write, free) must keep both equal to the recomputed sums,
+    and `check_invariants` catches a counter that drifted."""
+    cfg = tiny_cfg()
+    pc = PagedKVCache(cfg, n_slots=3, max_len=16, block_size=4)
+    pool = pc.pools[0]
+    ext = {}
+
+    def check(extra, shared):
+        assert pool.extra_refs() == extra
+        assert pool.shared_pages() == shared
+        g = pc.pool_gauges()[0]
+        s = pc.cross_layer_dedup_stats()
+        assert g["shared_refs"] == s["extra_refs"] == extra
+        assert s["shared_pages"] == shared
+        pc.check_invariants(ext)
+
+    pc.write_suffix(0, _stamp_kv([1, 2, 3, 4, 5]), _stamp_kv([1, 2, 3, 4, 5]),
+                    0, 5)                        # draws: refcount 1
+    pages = list(pc.owned_blocks(0))
+    check(0, 0)
+    pc.attach_shared(1, pages[:1])               # attach: 2
+    check(1, 1)
+    pc.retain(pages[0])                          # an index retain: 3
+    ext = {pages[0]: 1}
+    check(2, 1)
+    pc.attach_shared(2, pages)                   # 4 and 2
+    check(4, 2)
+    pc.write_suffix(1, _stamp_kv([7, 8]), _stamp_kv([7, 8]), 3, 2)
+    assert pc.cow_events == 1                    # copy-on-write: 3
+    check(3, 2)
+    pc.free_slot(2)                              # release: 2 and 1
+    check(1, 1)
+    pc.release(pages[0])                         # index release: 1
+    ext = {}
+    check(0, 0)
+    pool._extra_refs += 1
+    with pytest.raises(AssertionError, match="extra-refs counter"):
+        pc.check_invariants(ext)
+    pool._extra_refs -= 1
+    pool._shared_pages += 1
+    with pytest.raises(AssertionError, match="shared-pages counter"):
+        pc.check_invariants(ext)
+
+
 # ---------------------------------------------------------------------------
 # refcount / copy-on-write page lifecycle
 # ---------------------------------------------------------------------------
