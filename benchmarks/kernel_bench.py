@@ -479,8 +479,9 @@ def bucketed_serve_smoke() -> List[Row]:
     single, t_single, tel_single = drain("none")
     assert buck == single, "bucketed serving diverged from single-launch"
     # the structural win, end-to-end: the pow2 drain's telemetry-counted
-    # streamed bytes must undercut the single-launch full-depth walk
-    # ("none" builds no plans, so its accounting IS the full walk)
+    # streamed bytes must undercut the single launch's ("none" builds no
+    # plans, so its prefill accounting IS the full walk; decode copies
+    # only live pages under either strategy)
     sb_buck = tel_buck.streamed_bytes_total
     sb_single = tel_single.streamed_bytes_total
     assert sb_buck < sb_single, (sb_buck, sb_single)

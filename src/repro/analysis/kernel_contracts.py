@@ -25,8 +25,9 @@ without tracing:
   KC104  issue-before-fold ordering: the first `.start()` must precede
          the first `.wait()` (the double-buffer warm-up), otherwise
          the pipeline serializes (or deadlocks on real hardware).
-  KC105  wait-before-use: no subscript read of a buffer handed to
-         `double_buffered_page_walk` before the walk call returns —
+  KC105  wait-before-use: no subscript read of a buffer handed to a
+         page walk (`double_buffered_page_walk`,
+         `double_buffered_block_walk`) before the walk call returns —
          the landing buffers hold garbage until the walk's wait.
   KC106  a grid spec with ANY-space operands is a DMA kernel and must
          declare `pltpu.SemaphoreType.DMA` scratch.
@@ -51,6 +52,10 @@ KERNEL_FILES = (
     "src/repro/kernels/paged_attention.py",
     "src/repro/kernels/paged_prefill.py",
 )
+
+
+#: the shared page walks whose buffer arguments KC105 guards
+_PAGE_WALKS = ("double_buffered_page_walk", "double_buffered_block_walk")
 
 
 def _func_name(call: ast.Call) -> str:
@@ -150,7 +155,7 @@ class _FunctionChecker:
     def _check_walk_buffer_reads(self):
         walk_call = None
         for call in _calls(self.fn):
-            if _func_name(call).endswith("double_buffered_page_walk"):
+            if _func_name(call).endswith(_PAGE_WALKS):
                 walk_call = call
                 break
         if walk_call is None:

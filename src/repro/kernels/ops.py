@@ -155,29 +155,60 @@ def make_bucket_plan(
 def plan_streamed_pages(
     plan: Optional[BucketPlan], n_slots: int, table_width: int
 ) -> int:
-    """Table entries (pages per pool) one dispatch walks: the structural
-    data-movement quantity `benchmarks/kernel_bench.py` sweeps. `None`
-    (single launch) walks every slot's full table."""
+    """Table entries (pages per pool) one dispatch's grid spans: the
+    structural data-movement quantity `benchmarks/kernel_bench.py`
+    sweeps, and what the one-page-a-step prefill walk streams. `None`
+    (single launch) spans every slot's full table. The decode walk
+    copies only its slots' live pages inside this extent
+    (`decode_walk_pages`)."""
     if plan is None:
         return n_slots * table_width
     return sum(bound * count for bound, count in plan)
+
+
+def decode_walk_pages(
+    eff_lengths, first_blocks, block_size: int, pages_per_step: int,
+    plan: Optional[BucketPlan] = None,
+) -> int:
+    """Pages per pool one decode launch over one layer group copies
+    (DESIGN.md §10): each slot's walk runs from the P-column block
+    holding its first live column (`first_blocks`, the kernels'
+    `block_start`) through its last live column — at least one page, as
+    an idle slot folds one masked page — and each count-padding row of
+    a bucketed `plan` (a zero-length row) copies one page more."""
+    eff = np.maximum(np.asarray(eff_lengths).reshape(-1), 1)
+    n_cols = -(-eff // block_size)
+    first = np.minimum(
+        np.asarray(first_blocks).reshape(-1) // pages_per_step,
+        (n_cols - 1) // pages_per_step,
+    )
+    pages = int((n_cols - first * pages_per_step).sum())
+    if plan is not None:
+        pages += sum(count for _, count in plan) - eff.shape[0]
+    return pages
+
+
+def per_group_plans(plans, n_groups: int):
+    """The per-group form of the static half `bucket_args_grouped`
+    returns: a tuple of per-group plans (entries may be None), a single
+    plan shared by every group, or None for the everywhere-single-launch
+    path."""
+    if plans is None:
+        return (None,) * n_groups
+    if is_bucket_plan(plans):
+        return (plans,) * n_groups
+    return tuple(plans)
 
 
 def grouped_streamed_pages(
     plans, n_slots: int, table_width: int, n_groups: int = 1
 ):
     """Per-group `plan_streamed_pages` for one layer-major dispatch —
-    the telemetry layer's structural streamed-page accounting. `plans`
-    is the same static half `bucket_args_grouped` returned: a tuple of
-    per-group plans (entries may be None), a single plan, or None for
-    the everywhere-single-launch path (full-depth walk in every
-    group)."""
-    if plans is None:
-        return [n_slots * table_width] * n_groups
-    if is_bucket_plan(plans):
-        plans = (plans,) * n_groups
+    the extent of each group's grid, from the same plans
+    `bucket_args_grouped` returned (see `per_group_plans`)."""
     return [
-        plan_streamed_pages(p, n_slots, table_width) for p in plans
+        plan_streamed_pages(p, n_slots, table_width)
+        for p in per_group_plans(plans, n_groups)
     ]
 
 

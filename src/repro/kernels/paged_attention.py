@@ -9,13 +9,19 @@ fused call, no padding to a common length.
 The data-movement path is the one real hardware executes ("the BRAM is
 the limit" applied to TPU serving): the KV pools stay in ANY/HBM memory
 space and are never mapped whole into a grid step. Instead the block
-table, lengths and window ride in as **scalar-prefetch operands**
-(`PrefetchScalarGridSpec`), the grid is (slot, kv-block), and each step
-DMAs exactly one page per pool into a double-buffered VMEM scratch slot —
-the copy for step s+1 is issued before step s's fold, so on TPU the page
-walk overlaps compute and runs at HBM stream speed. The online-softmax
-state (m, l, acc) is carried across a slot's kv-block steps in VMEM
-scratch; the last step normalizes and writes the slot's output block.
+table, walk starts, lengths and window ride in as **scalar-prefetch
+operands** (`PrefetchScalarGridSpec`), and the grid is (slot, block):
+each step covers a block of P table columns (`decode_pages_per_step`,
+from the page bytes and the table width), aligned to multiples of P.
+Only a slot's live blocks are walked — from the block holding its first
+live column through the block holding its last — and within a block
+only the pages up to the last live column are DMA'd, into a
+double-buffered VMEM scratch; the next live block's copies are issued
+before this block's fold, so the walk overlaps compute. Steps past a
+slot's last live block do nothing. The fold scores a whole block at
+once per KV head; the online-softmax state (m, l, acc) is carried
+across a slot's steps in VMEM scratch, and the last step normalizes and
+writes the slot's output block (DESIGN.md §10).
 
 Layouts:
     q            [B, H, hd]                 one query token per slot
@@ -26,11 +32,12 @@ Layouts:
     window       [1] int32                  sliding window (cache capacity
                                             = full attention)
 
-Every (slot, kv-block) step folds with the same masked math as the
-`ref.paged_attention_ref` oracle — including steps past a slot's length,
-whose contributions cancel through the online-softmax rescale — so
-interpret mode on CPU is bit-comparable to the oracle across ragged
-lengths, windows, and COW-fragmented tables (the parity tests), and the
+Every block folds with the same masked math as the
+`ref.paged_attention_ref` oracle — including columns past a slot's
+length and a windowed slot's retired head, whose contributions cancel
+through the online-softmax rescale — so interpret mode on CPU matches
+the oracle on every row with length >= 1 across ragged lengths,
+windows, and COW-fragmented tables (the parity tests), and the
 identical body lowers natively on TPU.
 """
 
@@ -48,10 +55,10 @@ from .ops import resolve_impl
 from .paged_common import (
     bucketed_page_dispatch,
     check_quantized_operands as _check_quantized,
-    double_buffered_page_walk,
-    effective_walk_start,
+    decode_pages_per_step,
+    double_buffered_block_walk,
     finalize_online_softmax,
-    fold_kv_page,
+    fold_kv_block,
     page_scale_rows,
     reset_online_softmax,
 )
@@ -60,7 +67,7 @@ from .paged_common import (
 def _paged_decode_kernel(
     # scalar prefetch (SMEM)
     bt_ref,       # [B, max_blocks] int32
-    st_ref,       # [B] int32 — first live block per slot (walk start)
+    st_ref,       # [B] int32 — first live table column per slot
     len_ref,      # [B] int32
     win_ref,      # [1] int32
     # blocked / ANY operands, then outputs, then scratch — the exact
@@ -70,33 +77,32 @@ def _paged_decode_kernel(
     # float path refs:
     #   q_ref [1, KV, g, hd] VMEM | kp_hbm, vp_hbm [n_blocks, KV, bs, hd]
     #   ANY/HBM | out_ref [1, KV, g, hd] f32 VMEM | k_buf, v_buf
-    #   [2, KV, bs, hd] | m_s, l_s [KV, g, 1] f32 | acc_s [KV, g, hd] f32
-    #   | sem [2, 2]
-    # quantized path inserts ks_ref/vs_ref [1, max_blocks, KV] f32 VMEM
-    # (this slot's per-page scale rows) after the pools
+    #   [2, P, KV, bs, hd] | m_s, l_s [KV, g, 1] f32 | acc_s [KV, g, hd]
+    #   f32 | sem [2, 2] | buf_ref [1] int32 SMEM
+    # quantized path inserts ks_ref/vs_ref [1, ceil(max_blocks / P) * P,
+    # KV] f32 VMEM (this slot's per-page scale rows) after the pools
     block_size: int,
-    depth: int,   # walk depth of THIS launch (<= table width)
     quantized: bool,
 ):
     if quantized:
         (q_ref, kp_hbm, vp_hbm, ks_ref, vs_ref, out_ref,
-         k_buf, v_buf, m_s, l_s, acc_s, sem) = refs
+         k_buf, v_buf, m_s, l_s, acc_s, sem, buf_ref) = refs
     else:
         (q_ref, kp_hbm, vp_hbm, out_ref,
-         k_buf, v_buf, m_s, l_s, acc_s, sem) = refs
+         k_buf, v_buf, m_s, l_s, acc_s, sem, buf_ref) = refs
         ks_ref = vs_ref = None
     i = pl.program_id(0)               # slot
-    j = pl.program_id(1)               # kv block within the slot's table
-    n_steps = pl.num_programs(0) * depth
-    step = i * depth + j
-    mb = bt_ref.shape[1]
+    j = pl.program_id(1)               # block of the slot's walk
+    n_steps = pl.num_programs(1)
+    pages = k_buf.shape[1]
 
-    # double-buffered DMA: warm up step 0, prefetch step+1, wait step.
-    # The walk covers table columns [start, start + depth) — a windowed
-    # slot's retired head columns are skipped entirely (DESIGN.md §12)
-    cur = double_buffered_page_walk(
-        step, n_steps, bt_ref, depth, kp_hbm, vp_hbm, k_buf, v_buf, sem,
-        start_ref=st_ref,
+    # double-buffered DMA over live blocks only: the next live block is
+    # started before this one is waited on; blocks past the slot's
+    # length (and a windowed slot's retired head, DESIGN.md §12) are
+    # never visited
+    live, cur, first = double_buffered_block_walk(
+        i, j, pl.num_programs(0), n_steps, bt_ref, st_ref, len_ref,
+        block_size, kp_hbm, vp_hbm, k_buf, v_buf, sem, buf_ref,
     )
 
     # -- online-softmax fold (identical math to the ref oracle) -----------
@@ -104,19 +110,22 @@ def _paged_decode_kernel(
     def _():
         reset_online_softmax(m_s, l_s, acc_s)
 
-    length = len_ref[i]
-    window = win_ref[0]
-    q_pos = length - 1
-    col = effective_walk_start(st_ref, i, depth, mb) + j
-    kv_pos = col * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1
-    )                                                    # [1, bs] (2D: TPU)
-    ok = (kv_pos < length) & (kv_pos > q_pos - window)
-    fold_kv_page(
-        q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s, ks_ref, vs_ref, col
-    )
+    @pl.when(live)
+    def _():
+        length = len_ref[i]
+        window = win_ref[0]
+        q_pos = length - 1
+        col = (first + j) * pages
+        kv_pos = col * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, pages * block_size), 1
+        )                                                # [1, P*bs] (2D)
+        ok = (kv_pos < length) & (kv_pos > q_pos - window)
+        fold_kv_block(
+            q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s, ks_ref, vs_ref,
+            col,
+        )
 
-    @pl.when(j == depth - 1)
+    @pl.when(j == n_steps - 1)
     def _():
         finalize_online_softmax(l_s, acc_s, out_ref)
 
@@ -138,15 +147,24 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Pallas entry point; returns f32 [B, H, hd] attention outputs.
 
-    `depth` bounds the block walk: the grid becomes (B, depth) and table
-    columns outside [start, start + depth) are never DMA'd or folded.
-    The bucketed dispatch passes the bucket bound here; every slot in
-    the launch must hold its LIVE blocks inside that window or its tail
-    KV is silently skipped. `block_start` (default zeros) is the per-slot
-    first live block: a sliding-window layer retires its leading blocks
-    (DESIGN.md §12), and the walk starts past them — retired columns
-    point at scratch and are fully window-masked, so any start <= the
-    true first live block is bit-exact (start 0 = the full walk).
+    The walk folds blocks of P = `decode_pages_per_step` table columns,
+    aligned to multiples of P, from the block holding `block_start`
+    through the block holding the slot's last live position; blocks
+    past that are neither DMA'd nor folded. `depth` bounds the walk: the
+    grid becomes (B, blocks covering `depth` columns) and a slot whose
+    live columns run past `block_start + depth` silently loses its tail
+    KV — the bucketed dispatch passes the bucket bound here, which
+    covers every slot in its bucket. `block_start` (default zeros) is
+    the per-slot first live block: a sliding-window layer retires its
+    leading blocks (DESIGN.md §12), and the walk starts at the block
+    holding the first live one — retired columns point at scratch and
+    are fully window-masked, so any start <= the true first live block
+    is bit-exact (start 0 = the full walk).
+
+    Length-0 rows are don't-care: they fold one fully masked block and
+    come out finite. Every launch over one table folds the same aligned
+    blocks of every row with length >= 1, so a bucketed launch is
+    bit-identical to the single one there.
 
     `k_scales`/`v_scales` are required iff the pools are int8
     (DESIGN.md §16): each slot's scale rows then ride beside its block
@@ -161,20 +179,38 @@ def paged_decode_attention(
     depth = mb if depth is None else depth
     assert 1 <= depth <= mb, (depth, mb)
     g = h // n_kv
+    pages = decode_pages_per_step(
+        n_kv * bs * hd * k_pages.dtype.itemsize, mb
+    )
+    table_blocks = pl.cdiv(mb, pages)
+    # a walk that starts at an unaligned `block_start` spans one block
+    # more than its depth does
+    steps = min(
+        pl.cdiv(depth, pages) + (block_start is not None and depth < mb),
+        table_blocks,
+    )
     win = jnp.asarray(window, jnp.int32).reshape(1)
     if block_start is None:
         block_start = jnp.zeros((b,), jnp.int32)
+    bt = block_table.astype(jnp.int32)
     kernel = functools.partial(
-        _paged_decode_kernel, block_size=bs, depth=depth,
-        quantized=quantized,
+        _paged_decode_kernel, block_size=bs, quantized=quantized,
     )
+    pools = (k_pages, v_pages)
+    if quantized:
+        # each slot's scale rows, padded to whole blocks: the fold reads
+        # a block's P rows (the padding rows scale scratch page 0)
+        bt_blocks = jnp.pad(bt, ((0, 0), (0, table_blocks * pages - mb)))
+        pools += (page_scale_rows(k_scales, bt_blocks),
+                  page_scale_rows(v_scales, bt_blocks))
     pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2 + (
-        [pl.BlockSpec((1, mb, n_kv), lambda i, j, *_: (i, 0, 0))] * 2
+        [pl.BlockSpec((1, table_blocks * pages, n_kv),
+                      lambda i, j, *_: (i, 0, 0))] * 2
         if quantized else []
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,   # block_table, block_start, lengths, window
-        grid=(b, depth),
+        grid=(b, steps),
         in_specs=[
             pl.BlockSpec((1, n_kv, g, hd), lambda i, j, *_: (i, 0, 0, 0)),
             *pool_specs,         # K/V pools stay in HBM (+ scale rows)
@@ -183,18 +219,14 @@ def paged_decode_attention(
             (1, n_kv, g, hd), lambda i, j, *_: (i, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((2, n_kv, bs, hd), k_pages.dtype),
-            pltpu.VMEM((2, n_kv, bs, hd), v_pages.dtype),
+            pltpu.VMEM((2, pages, n_kv, bs, hd), k_pages.dtype),
+            pltpu.VMEM((2, pages, n_kv, bs, hd), v_pages.dtype),
             pltpu.VMEM((n_kv, g, 1), jnp.float32),
             pltpu.VMEM((n_kv, g, 1), jnp.float32),
             pltpu.VMEM((n_kv, g, hd), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
-    )
-    pools = (
-        (k_pages, v_pages, page_scale_rows(k_scales, block_table),
-         page_scale_rows(v_scales, block_table)) if quantized
-        else (k_pages, v_pages)
     )
     out = pl.pallas_call(
         kernel,
@@ -202,8 +234,8 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_table.astype(jnp.int32), block_start.astype(jnp.int32),
-      lengths.astype(jnp.int32), win, q.reshape(b, n_kv, g, hd), *pools)
+    )(bt, block_start.astype(jnp.int32), lengths.astype(jnp.int32), win,
+      q.reshape(b, n_kv, g, hd), *pools)
     return out.reshape(b, h, hd)
 
 

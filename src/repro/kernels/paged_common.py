@@ -1,16 +1,18 @@
 """Shared kernel pieces of the two paged-attention kernels.
 
-Both paged kernels (decode and prefill) walk a per-slot block table over
-a (slot, kv-block) grid, consume one K page + one V page per step from
-ANY/HBM-resident pools, and fold each page into a carried online
-softmax. The DMA pipeline and the fold math are identical in both —
-decode is simply the T=1 case of the fold — so both halves live here:
-a fix to the semaphore layout, the prefetch guard, or the softmax
-numerics (NEG_INF sentinel, alpha rescale, max(l, eps) epilogue) lands
-in exactly one place and cannot diverge between the kernels
-(DESIGN.md §10). The bucketed dispatch scaffold (DESIGN.md §11) lives
-here too: one launch per occupancy bucket, each walking only the bucket
-bound instead of the full table depth.
+Both paged kernels (decode and prefill) walk a per-slot block table,
+DMA K and V pages from ANY/HBM-resident pools into double-buffered VMEM
+scratch, and fold them into a carried online softmax. Decode walks a
+(slot, block) grid of `decode_pages_per_step` table columns a step and
+skips the blocks past each slot's length (`double_buffered_block_walk`);
+prefill walks a (slot, kv-block) grid one page a step
+(`double_buffered_page_walk`). The fold math is one function for both —
+decode is the T=1 case of the fold, a block just a wider page — so a fix
+to the softmax numerics (NEG_INF sentinel, alpha rescale, max(l, eps)
+epilogue) lands in exactly one place and cannot diverge between the
+kernels (DESIGN.md §10). The bucketed dispatch scaffold (DESIGN.md §11)
+lives here too: one launch per occupancy bucket, each walking only the
+bucket bound instead of the full table depth.
 
 Quantized KV pages (DESIGN.md §16) are also anchored here: the per-page
 int8 code <-> float conversion (`quantize_pages` / `dequantize_pages` /
@@ -36,6 +38,12 @@ NEG_INF = -1e30
 #: 3 KiB per query row and KV head), which passes the 16 MiB default at
 #: a few thousand rows; a v5e core has 128 MiB.
 VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+#: bytes of the P K pages plus P V pages one decode grid step may move:
+#: wide enough that the page copies, not the fixed cost of a grid step
+#: and of a block's fold, set the pace, while both double buffers (4
+#: MiB) stay well inside the default scoped VMEM (DESIGN.md §10)
+DECODE_BLOCK_BYTES = 2 * 1024 * 1024
 
 #: symmetric int8 quantization ceiling — codes live in [-128, 127], the
 #: per-(page, head) absmax maps to ±127 (same convention as the weight
@@ -148,6 +156,63 @@ def load_kv_page(k_buf, v_buf, cur, head, scale_rows=None):
     return kj, vj
 
 
+def load_kv_block(k_buf, v_buf, cur, head, scale_rows=None):
+    """Read KV head `head` of buffer `cur`'s block of P K/V pages as f32
+    [P * bs, hd], page after page. `scale_rows` (int8 pools) holds each
+    page's `(k_row, v_row)`: every page then dequantizes through
+    `load_kv_page` with its own per-head scale before the pages are
+    stacked. `scale_rows=None` is the float path: one load of the head's
+    P page planes and a tile-aligned merge of the page axis."""
+    if scale_rows is None:
+        kb = k_buf[cur, :, head].astype(jnp.float32)     # [P, bs, hd]
+        vb = v_buf[cur, :, head].astype(jnp.float32)
+        pages, bs, hd = kb.shape
+        return kb.reshape(pages * bs, hd), vb.reshape(pages * bs, hd)
+    kvs = [
+        load_kv_page(k_buf.at[cur], v_buf.at[cur], p, head, rows)
+        for p, rows in enumerate(scale_rows)
+    ]
+    return (jnp.concatenate([k for k, _ in kvs], axis=0),
+            jnp.concatenate([v for _, v in kvs], axis=0))
+
+
+def decode_pages_per_step(page_bytes: int, table_width: int) -> int:
+    """P, the table columns one decode grid step walks: the largest power
+    of two whose P K pages and P V pages fit `DECODE_BLOCK_BYTES`, and no
+    wider than the table. It follows the operands' shapes alone — one
+    pool page's bytes (KV heads x page size x head dim x itemsize) and
+    the table width — never the launch depth, so every launch over one
+    table (single or bucketed) folds the same aligned column ranges
+    (DESIGN.md §10)."""
+    pages = 1
+    while (
+        2 * pages <= table_width
+        and 2 * (2 * pages) * page_bytes <= DECODE_BLOCK_BYTES
+    ):
+        pages *= 2
+    return pages
+
+
+def live_columns(len_ref, slot, block_size: int):
+    """Table columns through `slot`'s last live position — at least one,
+    so an idle (length-0) slot still has a page to fold."""
+    return jnp.maximum((len_ref[slot] + block_size - 1) // block_size, 1)
+
+
+def live_blocks(slot, start_ref, len_ref, block_size: int, pages: int,
+                n_steps):
+    """`(first, count)` of `slot`'s decode walk: the first P-column block
+    it folds and how many blocks, through the last block holding a live
+    position. The walk starts at the block holding the slot's first live
+    column (`start_ref`, aligned down to a block boundary); a slot with
+    no live position (length 0, idle) still folds one block, so every
+    slot of a launch has a block to prefetch. `count` is capped at the
+    launch's grid steps (a bucketed launch's bound)."""
+    last = (live_columns(len_ref, slot, block_size) - 1) // pages
+    first = jnp.minimum(start_ref[slot] // pages, last)
+    return first, jnp.minimum(last - first + 1, n_steps)
+
+
 def effective_walk_start(start_ref, slot, depth: int, table_width: int):
     """First table column a `depth`-bounded walk visits for `slot`.
 
@@ -226,6 +291,110 @@ def double_buffered_page_walk(
     return cur
 
 
+def double_buffered_block_walk(
+    slot,         # grid index 0
+    step,         # grid index 1: the walk's block, counted from `first`
+    n_slots,      # grid extent 0
+    n_steps,      # grid extent 1: blocks a slot may walk in this launch
+    bt_ref,       # [B, max_blocks] int32 block table (scalar prefetch)
+    start_ref,    # [B] int32 first live column per slot
+    len_ref,      # [B] int32 valid kv count per slot
+    block_size: int,
+    kp_hbm,       # [n_blocks, KV, bs, hd] K pool — ANY/HBM ref
+    vp_hbm,       # V pool
+    k_buf,        # [2, P, KV, bs, hd] VMEM landing buffers
+    v_buf,
+    sem,          # DMA semaphores [2 buffers, 2 pools]
+    buf_ref,      # [1] int32 SMEM: the buffer the next live block lands in
+):
+    """Run one grid step of the decode walk over blocks of P table
+    columns. Returns `(live, cur, first)`: whether this step folds a
+    block, the buffer holding it, and the slot's first block (so the
+    step's block is `first + step`).
+
+    Only live pages move. The launch's first block is started at grid
+    step (0, 0); every live step starts the next live block — the
+    slot's next one, or the next slot's first when this is the slot's
+    last — into the other buffer before it waits on its own, so the
+    DMAs of block k+1 overlap the fold of block k. A block's copies stop
+    at the slot's last live column; the block's later page slots keep
+    what an earlier block left there (zeros at first), which the fold
+    masks. A step past the slot's last live block starts, waits and
+    folds nothing. Every started copy is waited exactly once, by the
+    live step that folds it; the copies of one block share their
+    buffer's semaphores."""
+    pages = k_buf.shape[1]
+
+    def n_live(s, blk):
+        """Pages of slot `s`'s block `blk` up to its last live column."""
+        return jnp.clip(
+            live_columns(len_ref, s, block_size) - blk * pages, 1, pages
+        )
+
+    def page_copies(s, blk, b, p):
+        """The K and V copies of page `p` of slot `s`'s block `blk` into
+        buffer `b` — recreated identically to start and to wait."""
+        page = bt_ref[s, blk * pages + p]
+        return (
+            pltpu.make_async_copy(
+                kp_hbm.at[pl.ds(page, 1)], k_buf.at[b, pl.ds(p, 1)],
+                sem.at[b, 0],
+            ),
+            pltpu.make_async_copy(
+                vp_hbm.at[pl.ds(page, 1)], v_buf.at[b, pl.ds(p, 1)],
+                sem.at[b, 1],
+            ),
+        )
+
+    def start_block(s, blk, b):
+        def body(p, carry):
+            for c in page_copies(s, blk, b, p):
+                c.start()
+            return carry
+        jax.lax.fori_loop(0, n_live(s, blk), body, 0)
+
+    def wait_block(s, blk, b):
+        def body(p, carry):
+            for c in page_copies(s, blk, b, p):
+                c.wait()
+            return carry
+        jax.lax.fori_loop(0, n_live(s, blk), body, 0)
+
+    first, count = live_blocks(
+        slot, start_ref, len_ref, block_size, pages, n_steps
+    )
+
+    @pl.when((slot == 0) & (step == 0))
+    def _():
+        # page slots past a slot's live columns are never copied into, and
+        # a masked score weights their values by exactly 0: zeros keep
+        # that product finite whatever the VMEM held before the launch
+        v_buf[...] = jnp.zeros_like(v_buf)
+        buf_ref[0] = 0
+        start_block(0, first, 0)
+
+    cur = buf_ref[0]
+    live = step < count
+
+    @pl.when(live & (step + 1 < count))
+    def _():
+        start_block(slot, first + step + 1, 1 - cur)
+
+    @pl.when(live & (step + 1 == count) & (slot + 1 < n_slots))
+    def _():
+        nxt_first, _ = live_blocks(
+            slot + 1, start_ref, len_ref, block_size, pages, n_steps
+        )
+        start_block(slot + 1, nxt_first, 1 - cur)
+
+    @pl.when(live)
+    def _():
+        buf_ref[0] = 1 - cur
+        wait_block(slot, first + step, cur)
+
+    return live, cur, first
+
+
 def reset_online_softmax(m_s, l_s, acc_s):
     """Start a slot's fold: -inf running max, zero normalizer/values."""
     m_s[...] = jnp.full_like(m_s, NEG_INF)
@@ -252,14 +421,45 @@ def fold_kv_page(q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s,
     scale_rows = None
     if ks_ref is not None:
         scale_rows = (ks_ref[0, pl.ds(col, 1), :], vs_ref[0, pl.ds(col, 1), :])
+    fold_heads(
+        q_ref, lambda h: load_kv_page(k_buf, v_buf, cur, h, scale_rows),
+        ok, m_s, l_s, acc_s,
+    )
+
+
+def fold_kv_block(q_ref, k_buf, v_buf, cur, ok, m_s, l_s, acc_s,
+                  ks_ref=None, vs_ref=None, col=None):
+    """`fold_kv_page` for a block: fold buffer `cur`'s P pages
+    ([2, P, KV, bs, hd] buffers) as one [R, P * bs] score block per KV
+    head, with one online-softmax update per block. `ok` is
+    broadcastable to [R, P * bs]; on quantized pools `col` is the
+    block's first table column and each page folds with its own scale
+    row."""
+    scale_rows = None
+    if ks_ref is not None:
+        scale_rows = [
+            (ks_ref[0, pl.ds(col + p, 1), :], vs_ref[0, pl.ds(col + p, 1), :])
+            for p in range(k_buf.shape[1])
+        ]
+    fold_heads(
+        q_ref, lambda h: load_kv_block(k_buf, v_buf, cur, h, scale_rows),
+        ok, m_s, l_s, acc_s,
+    )
+
+
+def fold_heads(q_ref, load_head, ok, m_s, l_s, acc_s):
+    """The fold itself: for each KV head, `load_head(h)` gives its f32
+    keys and values [N, hd] (one page, or a block of pages), scored
+    against the head's query rows [R, hd] under mask `ok` and folded
+    into the carried (m, l, acc) state."""
     n_kv, hd = q_ref.shape[1], q_ref.shape[3]
     for h in range(n_kv):
         qf = q_ref[0, h].astype(jnp.float32) * (hd ** -0.5)     # [R, hd]
-        kj, vj = load_kv_page(k_buf, v_buf, cur, h, scale_rows)
+        kj, vj = load_head(h)
         scores = jax.lax.dot_general(
             qf, kj, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                       # [R, bs]
+        )                                                       # [R, N]
         scores = jnp.where(ok, scores, NEG_INF)
         m, l, acc = m_s[h], l_s[h], acc_s[h]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))

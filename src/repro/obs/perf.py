@@ -147,20 +147,29 @@ def predict_launch(
     *,
     strategy: str = "pow2",
     kernel_impl: str = "auto",
+    phase: str = "",
 ) -> LaunchPrediction:
     """Full analytic model of one dispatch from pool geometry: per
     layer group, the live walk-entry counts (`bucket_needs` — window
     retirement already folded in via each pool's first live block),
-    the pow2 plan re-derived from them, and the three byte grades.
-    `phase` is filled by the caller."""
+    the pow2 plan re-derived from them, and the three byte grades. The
+    applicable grade of a `"decode"` launch is its block walk's copied
+    pages (`PagedKVCache.decode_walk_pages` under the plan the dispatch
+    builds), which stops at each slot's last live column."""
     needs = pcache.bucket_needs(eff_lengths, slots)
     mb = pcache.max_blocks_per_slot
     plb = pcache.page_layer_bytes
     bucketed = plans_enabled(strategy, kernel_impl)
+    walk_pages = None
+    if phase == "decode":
+        plans = tuple(
+            make_bucket_plan(None, 0, mb, needs=need)[0] for need in needs
+        ) if bucketed else None
+        walk_pages = pcache.decode_walk_pages(eff_lengths, plans)
     full_b = bucketed_b = live_b = 0
     pages_by_group: List[int] = []
     bytes_by_group: List[int] = []
-    for pool, need in zip(pcache.pools, needs):
+    for g, (pool, need) in enumerate(zip(pcache.pools, needs)):
         layers = len(pool.layers)
         full_pg = n_rows * mb
         buck_pg = predict_streamed_pages(need, n_rows, mb, bucketed=True)
@@ -169,10 +178,12 @@ def predict_launch(
         bucketed_b += layers * buck_pg * plb
         live_b += layers * live_pg * plb
         pg = buck_pg if bucketed else full_pg
+        if walk_pages is not None:
+            pg = walk_pages[g]
         pages_by_group.append(pg)
         bytes_by_group.append(layers * pg * plb)
     return LaunchPrediction(
-        phase="", n_rows=n_rows, pages_by_group=pages_by_group,
+        phase=phase, n_rows=n_rows, pages_by_group=pages_by_group,
         bytes_by_group=bytes_by_group, full_bytes=full_b,
         bucketed_bytes=bucketed_b, live_bytes=live_b,
     )
@@ -229,9 +240,8 @@ class PerfModel:
         per-group accounting, and record the model error."""
         pred = predict_launch(
             pcache, eff_lengths, slots, n_rows,
-            strategy=strategy, kernel_impl=kernel_impl,
+            strategy=strategy, kernel_impl=kernel_impl, phase=phase,
         )
-        pred.phase = phase
         r = self.registry
         measured_total = int(sum(measured_bytes_by_group))
         err = _rel_err(pred.bytes_total, measured_total)

@@ -242,14 +242,16 @@ class ServeTelemetry:
                              pcache, eff_lengths=None, slots=None,
                              strategy: Optional[str] = None,
                              kernel_impl: str = "auto") -> None:
-        """Streamed-page/byte accounting for one dispatch, derived from
-        the bucket plans (DESIGN.md §11-§13): per group, the table
-        entries the launch walks (`plans=None` = the full-depth walk),
-        weighted by the group's layer count and per-layer page bytes.
-        The quantity is structural — it is what the kernels' block walk
-        streams on the TPU path, and what the oracle path WOULD stream
-        (the roofline-validation number), machine-independent either
-        way.
+        """Streamed-page/byte accounting for one dispatch (DESIGN.md
+        §10-§13): per group, the pages the launch copies, weighted by
+        the group's layer count and per-layer page bytes. Prefill copies
+        its grid's whole extent (`plans=None` = the full-depth walk);
+        decode, given `eff_lengths`, copies only its slots' live pages
+        (`PagedKVCache.decode_walk_pages`), and the rest of the extent
+        counts as `kernel_skipped_pages{kind="decode"}`. The quantity is
+        structural — it is what the kernels' walk streams on the TPU
+        path, and what the oracle path WOULD stream (the
+        roofline-validation number), machine-independent either way.
 
         When the caller also passes the launch's geometry inputs
         (`eff_lengths`/`slots`, plus the dispatch policy `strategy` /
@@ -259,6 +261,12 @@ class ServeTelemetry:
         pages = grouped_streamed_pages(
             plans, n_rows, pcache.max_blocks_per_slot, len(pcache.pools)
         )
+        if kind == "decode" and eff_lengths is not None:
+            extent = pages
+            pages = pcache.decode_walk_pages(eff_lengths, plans)
+            self.registry.counter(
+                "kernel_skipped_pages", {"kind": kind}
+            ).inc(int(sum(extent) - sum(pages)))
         plb = pcache.page_layer_bytes
         bytes_by_group = [
             len(pool.layers) * pg * plb

@@ -60,7 +60,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..kernels.ops import decode_walk_pages, per_group_plans
 from ..kernels.paged_common import (
+    decode_pages_per_step,
     pages_to_tokens,
     requantize_page_update,
     tokens_to_pages,
@@ -1004,6 +1006,24 @@ class PagedKVCache:
         return [
             np.maximum(blocks - p.first_block[idx], 1)
             for p in self.pools
+        ]
+
+    def decode_walk_pages(self, eff_lengths, plans=None) -> List[int]:
+        """Per-group pages one decode launch's block walk copies
+        (`kernels.ops.decode_walk_pages`, DESIGN.md §10): every slot's
+        walk from the block holding its group's first live column
+        through its last live column, plus a page per count-padding row
+        of the group's bucket plan."""
+        _, _, kvh, bs, hd = self.k_pages.shape
+        pages = decode_pages_per_step(
+            kvh * bs * hd * jnp.dtype(self.k_pages.dtype).itemsize,
+            self.max_blocks_per_slot,
+        )
+        return [
+            decode_walk_pages(eff_lengths, p.first_block, bs, pages, plan)
+            for p, plan in zip(
+                self.pools, per_group_plans(plans, len(self.pools))
+            )
         ]
 
     # -- accounting (DESIGN.md §12) ----------------------------------------

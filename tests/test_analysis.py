@@ -324,13 +324,16 @@ def test_kc106_any_operands_without_dma_semaphore(tmp_path):
     assert rules(found) == {"KC106"}
 
 
-def test_kc105_read_before_walk(tmp_path):
-    found = _kernel_fixture(tmp_path, """
-        from repro.kernels.paged_common import double_buffered_page_walk
+@pytest.mark.parametrize(
+    "walk", ["double_buffered_page_walk", "double_buffered_block_walk"]
+)
+def test_kc105_read_before_walk(tmp_path, walk):
+    found = _kernel_fixture(tmp_path, f"""
+        from repro.kernels.paged_common import {walk}
 
         def bad_kernel(step, n, bt_ref, kp, vp, k_buf, v_buf, sem, o_ref):
             early = k_buf[0]
-            cur = double_buffered_page_walk(
+            cur = {walk}(
                 step, n, bt_ref, 4, kp, vp, k_buf, v_buf, sem
             )
             o_ref[...] = early + v_buf[cur]

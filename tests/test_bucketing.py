@@ -46,8 +46,15 @@ def test_plan_and_permutation_round_trip(data):
     )
     n = len(lengths)
     plan, perm = ops.make_bucket_plan(lengths, bs, mb)
+    # the decode walk copies each slot's live columns (one for an idle
+    # slot) and a page per padding row: never more than the plan's extent
+    pages = data.draw(st.sampled_from([1, 2, 4, 8]), label="pages")
+    live = sum(max(-(-x // bs), 1) for x in lengths)
+    walk = ops.decode_walk_pages(lengths, [0] * n, bs, pages, plan)
+    assert live <= walk <= ops.plan_streamed_pages(plan, n, mb)
     if plan is None:
         assert perm is None
+        assert walk == live
         assert ops.plan_streamed_pages(plan, n, mb) == n * mb
         return
     # structure: pow2 bounds (clipped to mb) and pow2 counts, ascending

@@ -374,6 +374,24 @@ def test_streamed_page_accounting_full_depth_vs_plan():
     assert grouped_streamed_pages(((2, 4),), 4, 8, n_groups=2) == [8, 8]
 
 
+def test_decode_walk_pages_counts_live_columns():
+    """The decode walk copies each slot's pages from the P-page block
+    holding its first live column through its last live column — one
+    page for an idle slot — plus one page per count-padding row."""
+    from repro.kernels.ops import decode_walk_pages
+
+    eff, bs, p = [1, 5, 17, 0], 4, 4          # 1, 2, 5 and 1 columns
+    assert decode_walk_pages(eff, [0, 0, 0, 0], bs, p) == 9
+    # a windowed slot 2 retired 4 columns: its walk starts at block 1
+    assert decode_walk_pages(eff, [0, 0, 4, 0], bs, p) == 5
+    # an unaligned start walks from the block holding it
+    assert decode_walk_pages(eff, [0, 0, 3, 0], bs, p) == 9
+    # a start past an idle slot's single column still walks one page
+    assert decode_walk_pages(eff, [6, 0, 0, 0], bs, p) == 9
+    # a bucketed plan's 6 rows hold 2 zero-length padding rows
+    assert decode_walk_pages(eff, [0] * 4, bs, p, ((2, 2), (8, 4))) == 11
+
+
 # ---------------------------------------------------------------------------
 # serve-stack integration
 # ---------------------------------------------------------------------------
@@ -543,3 +561,15 @@ def test_streamed_bytes_accounted_per_launch(model):
         + launches("kernel_streamed_bytes", {"kind": "decode"}).value
     )
     assert by_kind == total
+    # the decode walk copies live pages only: the rest of every launch's
+    # 2 x 8-column extent is skipped, and what it copies costs less than
+    # the full walk would
+    pc = cb.pcache
+    (pool,) = pc.pools
+    extent = n_decode * 2 * pc.max_blocks_per_slot
+    skipped = launches("kernel_skipped_pages", {"kind": "decode"}).value
+    assert 0 < skipped < extent
+    decode_bytes = launches("kernel_streamed_bytes", {"kind": "decode"}).value
+    assert decode_bytes == (
+        (extent - skipped) * len(pool.layers) * pc.page_layer_bytes
+    )

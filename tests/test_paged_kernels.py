@@ -2,9 +2,12 @@
 oracles across the full ragged/window/offset/COW matrix, plus the strict
 impl-dispatch rules (ISSUE 3 / DESIGN.md §10).
 
-The kernels fold every block-table page with the oracle's exact masked
-math, so parity must hold for ALL rows — including don't-care outputs
-(length-0 idle slots, padded suffix rows past `total`).
+The kernels fold block-table pages with the oracle's exact masked math,
+so parity must hold for every valid row, and for prefill's don't-care
+rows too (padded suffix rows past `total`). Decode's walk stops at each
+slot's last live block, so a length-0 row folds one fully masked block
+where the oracle folds the whole table: those rows are don't-care and
+only have to come out finite.
 
 Every parity case additionally pins the length-bucketed dispatch
 (DESIGN.md §11) bit-identical to the single launch on valid rows — the
@@ -45,11 +48,25 @@ def _pools(rng, nb, bs, kv, hd, dtype=jnp.float32):
     return kp, vp
 
 
-def _assert_decode_parity(q, kp, vp, bt, lengths, window):
+def _assert_decode_rows(got, want, lengths, tol=TOL):
+    """Decode parity on every row with length >= 1; length-0 rows are
+    don't-care and only have to be finite."""
+    got = np.asarray(got)
+    valid = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[valid], np.asarray(want)[valid], **tol)
+    assert np.isfinite(got).all()
+
+
+def _assert_decode_parity(q, kp, vp, bt, lengths, window, block_start=None):
     a = ref.paged_attention_ref(q, kp, vp, bt, lengths, window)
-    b = paged_decode_attention(q, kp, vp, bt, lengths, window, interpret=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL)
-    _assert_bucketed_decode_matches_single(q, kp, vp, bt, lengths, window)
+    b = paged_decode_attention(
+        q, kp, vp, bt, lengths, window, block_start=block_start,
+        interpret=True,
+    )
+    _assert_decode_rows(b, a, lengths)
+    _assert_bucketed_decode_matches_single(
+        q, kp, vp, bt, lengths, window, block_start
+    )
 
 
 def _assert_prefill_parity(q, kp, vp, bt, start, total, window):
@@ -62,19 +79,28 @@ def _assert_prefill_parity(q, kp, vp, bt, start, total, window):
                                             window)
 
 
-def _assert_bucketed_decode_matches_single(q, kp, vp, bt, lengths, window):
+def _assert_bucketed_decode_matches_single(q, kp, vp, bt, lengths, window,
+                                           block_start=None):
     """DESIGN.md §11: the bucketed dispatch is bit-identical to the
-    single launch on every slot with length >= 1 (the cut tail pages
-    fold as exact no-ops); length-0 rows are don't-care either way."""
+    single launch on every slot with length >= 1 (both fold the same
+    aligned blocks); length-0 rows are don't-care either way. With
+    `block_start` the slots bucket by live trailing blocks, as a
+    windowed layer's do (DESIGN.md §12)."""
     lens = np.asarray(lengths)
-    plan, perm = ops.make_bucket_plan(lens, kp.shape[2], bt.shape[1])
+    bs, mb = kp.shape[2], bt.shape[1]
+    needs = None
+    if block_start is not None:
+        needs = -(-np.maximum(lens, 1) // bs) - np.asarray(block_start)
+    plan, perm = ops.make_bucket_plan(lens, bs, mb, needs=needs)
     if plan is None:  # degenerate plan: single launch IS the dispatch
         return
     single = paged_decode_attention(
-        q, kp, vp, bt, lengths, window, interpret=True
+        q, kp, vp, bt, lengths, window, block_start=block_start,
+        interpret=True,
     )
     bucketed = paged_decode_attention_bucketed(
-        q, kp, vp, bt, lengths, window, plan, perm, interpret=True
+        q, kp, vp, bt, lengths, window, plan, perm,
+        block_start=block_start, interpret=True,
     )
     valid = lens > 0
     np.testing.assert_array_equal(
@@ -108,10 +134,16 @@ def _assert_bucketed_prefill_matches_single(q, kp, vp, bt, start, total,
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("window", [64, 3, 1])
-@pytest.mark.parametrize("lengths", [[5, 12, 1], [0, 12, 4], [0, 0, 1]])
+@pytest.mark.parametrize(
+    "lengths", [[5, 12, 1], [0, 12, 4], [0, 0, 1], [8, 9, 3]]
+)
 def test_decode_ragged_lengths_and_windows(rng, window, lengths):
     """Ragged lengths including idle (0) and single-token (1) slots, full
-    attention and sliding windows shorter than the longest length."""
+    attention and sliding windows shorter than the longest length. The
+    3-column tables walk blocks of P = 2 pages (8 tokens), so the table
+    width is not a multiple of P, and the lengths end mid-page (3, 5),
+    exactly at a block edge (8), one token into the next block (9) and
+    one page past a block edge (12)."""
     B, H, KV, hd, bs, nb, mb = 3, 4, 2, 8, 4, 10, 3
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
     kp, vp = _pools(rng, nb, bs, KV, hd)
@@ -122,6 +154,71 @@ def test_decode_ragged_lengths_and_windows(rng, window, lengths):
         q, kp, vp, bt, jnp.asarray(lengths, jnp.int32),
         jnp.asarray(window, jnp.int32),
     )
+
+
+def test_decode_idle_and_parked_slots(rng):
+    """The serving batch's non-decoding rows, beside slots that walk
+    several blocks: an idle slot (length 1 over its own table) and a
+    parked one (length 1 over an all-scratch table row), as the
+    scheduler passes them (`eff` = 1). Each walks one block."""
+    B, H, KV, hd, bs, nb, mb = 4, 4, 2, 8, 4, 24, 5
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
+    kp, vp = _pools(rng, nb, bs, KV, hd)
+    bt = np.asarray(
+        rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), np.int32
+    )
+    bt[2] = 0                                    # parked: scratch row
+    _assert_decode_parity(
+        q, kp, vp, jnp.asarray(bt), jnp.asarray([19, 1, 1, 9], jnp.int32),
+        jnp.asarray(mb * bs, jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("window", [3, 9, 64])
+def test_decode_windowed_walk_start(rng, window):
+    """A sliding-window layer's walk starts at the block holding its
+    first live column (`block_start`, DESIGN.md §12), aligned down to a
+    block boundary: with P = 4 pages, starts 5 and 3 are unaligned, 0
+    is not. The bucketed launches (by live trailing pages, one grid step
+    more than their bound for the unaligned start) stay bit-identical
+    to the single one."""
+    B, H, KV, hd, bs, nb, mb = 3, 4, 2, 8, 4, 20, 6
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
+    kp, vp = _pools(rng, nb, bs, KV, hd)
+    bt = jnp.asarray(
+        rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32
+    )
+    lengths = np.asarray([24, 21, 10])
+    start = np.maximum(lengths - window, 0) // bs
+    _assert_decode_parity(
+        q, kp, vp, bt, jnp.asarray(lengths, jnp.int32),
+        jnp.asarray(window, jnp.int32), jnp.asarray(start, jnp.int32),
+    )
+
+
+def test_decode_pages_per_step_rule():
+    """P comes from one pool page's bytes and the table width alone —
+    never from a launch's depth, so every launch over one table folds
+    the same aligned blocks. At the cells' shapes: qwen2-1.5b's 2 x 16 x
+    128 bf16 pages over 288-column tables walk 128 pages a step,
+    qwen2.5-14b's 8 x 16 x 128 pages over 736 columns 32."""
+    import inspect
+
+    from repro.kernels.paged_common import decode_pages_per_step
+
+    assert list(inspect.signature(decode_pages_per_step).parameters) == [
+        "page_bytes", "table_width",
+    ]
+    assert decode_pages_per_step(2 * 16 * 128 * 2, 288) == 128
+    assert decode_pages_per_step(8 * 16 * 128 * 2, 736) == 32
+    # the same page bytes in another head layout: the same P
+    assert decode_pages_per_step(4 * 16 * 64 * 2, 288) == 128
+    # int8 pages are half as wide, so twice as many fit a step
+    assert decode_pages_per_step(8 * 16 * 128 * 1, 736) == 64
+    # never wider than the table: the largest power of two within it
+    assert [decode_pages_per_step(256, w) for w in (1, 2, 3, 5, 8)] == [
+        1, 2, 2, 4, 8,
+    ]
 
 
 def test_decode_single_block_table(rng):
@@ -255,7 +352,7 @@ def test_prefill_cow_fragmented_tables(rng):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("window", [64, 3])
-@pytest.mark.parametrize("lengths", [[5, 12, 1], [0, 12, 4]])
+@pytest.mark.parametrize("lengths", [[5, 12, 1], [0, 12, 4], [8, 9, 3]])
 def test_decode_int8_parity(rng, window, lengths):
     """int8 pools through the same kernel body: tight (TOL) vs the
     quantized oracle — both fold the identical dequantized codes — and
@@ -273,17 +370,16 @@ def test_decode_int8_parity(rng, window, lengths):
     out = np.asarray(paged_decode_attention(
         q, kq, vq, bt, lens, win, k_scales=ks, v_scales=vs, interpret=True
     ))
-    np.testing.assert_allclose(
+    _assert_decode_rows(
         out,
-        np.asarray(ref.paged_attention_ref(
+        ref.paged_attention_ref(
             q, kq, vq, bt, lens, win, k_scales=ks, v_scales=vs
-        )),
-        **TOL,
+        ),
+        lens,
     )
-    np.testing.assert_allclose(
-        out,
-        np.asarray(ref.paged_attention_ref(q, kp, vp, bt, lens, win)),
-        **INT8_TOL,
+    _assert_decode_rows(
+        out, ref.paged_attention_ref(q, kp, vp, bt, lens, win), lens,
+        INT8_TOL,
     )
 
 
@@ -474,17 +570,19 @@ def test_bucketed_plan_threads_through_dispatch(rng):
         np.testing.assert_allclose(got2[i, :tv], want2[i, :tv], **TOL)
 
 
-def test_depth_bounds_the_walk(rng):
+@pytest.mark.parametrize("mb", [4, 7])
+def test_depth_bounds_the_walk(rng, mb):
     """An explicit `depth` must reproduce the full walk whenever it
     covers every valid page — the tail columns it cuts are exact no-ops
-    (this is the exactness the bucketed dispatch rests on)."""
-    B, H, KV, hd, bs, nb, mb = 2, 4, 2, 8, 4, 12, 4
+    (this is the exactness the bucketed dispatch rests on). At width 7
+    the walk's blocks are 4 pages and a depth of 2 spans half a block."""
+    B, H, KV, hd, bs, nb = 2, 4, 2, 8, 4, 16
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
     kp, vp = _pools(rng, nb, bs, KV, hd)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, nb))[: B * mb].reshape(B, mb), jnp.int32
     )
-    lengths = jnp.asarray([7, 5], jnp.int32)       # 2 pages each, mb = 4
+    lengths = jnp.asarray([7, 5], jnp.int32)       # 2 pages each
     win = jnp.asarray(mb * bs, jnp.int32)
     full = paged_decode_attention(q, kp, vp, bt, lengths, win, interpret=True)
     shallow = paged_decode_attention(

@@ -309,6 +309,17 @@ def test_predict_launch_grades(geo_drain):
     p2 = perf_mod.predict_launch(
         pc, eff, None, 4, strategy="pow2", kernel_impl="ref")
     assert p2.bytes_total == p2.full_bytes == p1.full_bytes
+    # a decode launch's applicable grade is its block walk's copied
+    # pages: at least the live ones, within the plan's extent
+    p3 = perf_mod.predict_launch(
+        pc, eff, None, 4, strategy="pow2", kernel_impl="pallas_interpret",
+        phase="decode")
+    assert p3.live_bytes <= p3.bytes_total <= p3.bucketed_bytes
+    assert p3.pages_by_group == pc.decode_walk_pages(
+        eff, tuple(
+            make_bucket_plan(None, 0, pc.max_blocks_per_slot, needs=n)[0]
+            for n in pc.bucket_needs(eff)
+        ))
 
 
 # ---------------------------------------------------------------------------
