@@ -23,7 +23,8 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 
 #: read beside the cell's own end-to-end metrics
-EXTRA = [("ttft_p50_ms", "ms"), ("ttft_p90_ms", "ms"), ("tpot_p50_ms", "ms"),
+EXTRA = [("ttft_mean_ms", "ms"), ("ttft_p50_ms.serve", "ms"),
+         ("ttft_p90_ms", "ms"), ("tpot_p50_ms", "ms"),
          ("output_tok_s", "tokens/s")]
 
 

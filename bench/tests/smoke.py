@@ -1,38 +1,46 @@
-"""Small copies of the benchmark's cells for tests on the CPU."""
+"""Small copies of the benchmark's cells for tests on the CPU.
 
+The configurations and cells are BENCHMARK.json's. Each cell's CPU
+settings are in `bench/tests/cells/<workload>.json`: `mix` (top-level
+keys of the traffic mix that replace the cell's), `server` (settings
+over the cell's), and `max_logit_gap`, the check's limit at this size,
+with the readings it was set from in `limit_from`. A cell added with
+such a file is tested by every test that walks `CELLS`.
+"""
+
+import json
+import os
 import time
 
 from bench import harness, model
 
-SMOKE_MIX = {
-    "qwen2-1.5b.chat": {"arrivals": dict(
-        phases=[{"seconds": 1.0, "rate_per_s": 4.0}], ramp_s=1.0,
-        prompt_len_pool=[3, 9, 20, 37],
-        output_len={"median": 16, "sigma": 0.5, "min": 8, "max": 40})},
-    "qwen2.5-14b-12l.long_decode": {"sessions": dict(
-        context_len_pool=[40, 75], max_new_tokens=30)},
-}
-#: the `max_logit_gap` limit at this size (4 s windows, about 300
-#: tokens compared in chat, 60 in long_decode), set between the sound
-#: runs' widest gaps (chat <= 0.0125, long_decode <= 0.0235 over six
-#: seeds each) and the float8 control's (chat >= 0.036, long_decode
-#: >= 0.087)
-SMOKE_LIMIT = {"qwen2-1.5b.chat": 0.025, "qwen2.5-14b-12l.long_decode": 0.04}
+with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+CONFIGS = [c["name"] for c in BENCHMARK["configs"]]
+CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 SMOKE_CHECK = dict(sample_requests=16, sample_tokens=600)
-SMOKE_SERVER = {
-    "qwen2-1.5b.chat": dict(n_slots=4, max_len=96, prefill_chunk=32),
-    "qwen2.5-14b-12l.long_decode": dict(n_slots=2, max_len=112,
-                                        prefill_chunk=32),
-}
+
+
+def settings(name: str) -> dict:
+    with open(os.path.join(harness.BENCH_DIR, "tests", "cells",
+                           f"{name}.json")) as f:
+        return json.load(f)
 
 
 def cell(name: str, **hf_over) -> harness.Cell:
     c = harness.load_cell(name)
+    s = settings(name)
     c.hf = model.smoke_config(c.hf, **hf_over)
-    c.mix = dict(c.mix, **SMOKE_MIX[name])
-    c.server = dict(c.server, **SMOKE_SERVER[name])
-    c.check = dict(c.check, max_logit_gap=SMOKE_LIMIT[name], **SMOKE_CHECK)
+    c.mix = dict(c.mix, **s["mix"])
+    c.server = dict(c.server, **s["server"])
+    c.check = dict(c.check, max_logit_gap=s["max_logit_gap"], **SMOKE_CHECK)
     return c
+
+
+def first_cell(open_loop: bool) -> str:
+    """The first cell whose mix has open-loop arrivals (or has none)."""
+    return next(n for n in CELLS
+                if ("arrivals" in harness.load_cell(n).mix) == open_loop)
 
 
 def _no_cache(root=None):

@@ -10,8 +10,7 @@ import pytest
 import smoke
 
 
-@pytest.mark.parametrize("name", ["qwen2-1.5b.chat",
-                                  "qwen2.5-14b-12l.long_decode"])
+@pytest.mark.parametrize("name", smoke.CELLS)
 def test_float8_control_fails_where_the_program_passes(name):
     program, control = [], []
     for seed in (3, 2**31 + 5):
@@ -20,5 +19,5 @@ def test_float8_control_fails_where_the_program_passes(name):
         assert r["control_correct"] is False, r["control_checks"]
         program.append(r["checks"]["max_logit_gap"]["value"])
         control.append(r["control_checks"]["max_logit_gap"]["value"])
-    assert min(control) > smoke.SMOKE_LIMIT[name]
+    assert min(control) > smoke.settings(name)["max_logit_gap"]
     assert min(control) > max(program)

@@ -1,17 +1,22 @@
-"""The harness finds configurations, traffic mixes and per-layer metrics
-by name from files of their own: a new mix and a new metric, added as
-new files beside copies of the existing ones, are picked up with no
-edit to any file that was there."""
+"""The harness finds architectures, configurations, traffic mixes and
+per-layer metrics by name from files of their own: a new mix and a new
+metric, added as new files beside copies of the existing ones, and a
+new architecture with its reference, configuration, cell, mix and CPU
+settings, are picked up with no edit to any file that was there."""
 
 import json
 import os
 import shutil
+import subprocess
+import sys
 
 import smoke
 from bench import harness, peaks
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+#: the open-loop cell the new mix and metrics ride on
+TEMPLATE = smoke.first_cell(open_loop=True)
 
 DUMMY_METRIC = '''"""Requests still queued when the window closed (test metric)."""
 
@@ -58,24 +63,24 @@ def test_new_mix_and_metric_files_are_picked_up(tmp_path):
     (root / "bench" / "metrics" / "dummy_ticks.py").write_text(DUMMY_METRIC)
     (root / "bench" / "metrics" / "dummy_done.py").write_text(DUMMY_E2E)
     cell = json.loads((root / "bench" / "cells" /
-                       "qwen2-1.5b.chat.json").read_text())
+                       f"{TEMPLATE}.json").read_text())
     cell["server"]["prefix"] = True
-    (root / "bench" / "cells" / "qwen2-1.5b.dummy_burst.json").write_text(
-        json.dumps(cell))
+    config = harness.load_cell(TEMPLATE).config
+    name = f"{config}.dummy_burst"
+    (root / "bench" / "cells" / f"{name}.json").write_text(json.dumps(cell))
     bench["workloads"].append({
-        "name": "qwen2-1.5b.dummy_burst", "config": "qwen2-1.5b",
+        "name": name, "config": config,
         "traffic": "dummy_burst", "chips": 1, "why": "test"})
     bench["end_to_end"].append({
         "name": "dummy_done", "unit": "count", "better": "higher",
-        "bound": 0.25, "source": "host_clock",
-        "workloads": ["qwen2-1.5b.dummy_burst"]})
+        "bound": 0.25, "source": "host_clock", "workloads": [name]})
     bench["per_layer"].append({
         "name": "dummy_ticks", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "serve.scheduler",
-        "moves": "dummy_done", "workloads": ["qwen2-1.5b.dummy_burst"]})
+        "moves": "dummy_done", "workloads": [name]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
-    cell = harness.load_cell("qwen2-1.5b.dummy_burst", root=str(root))
+    cell = harness.load_cell(name, root=str(root))
     assert cell.mix == BURST_MIX
     assert [m["name"] for m in cell.per_layer][-1] == "dummy_ticks"
     assert [m["name"] for m in cell.end_to_end][-1] == "dummy_done"
@@ -88,10 +93,11 @@ def test_new_mix_and_metric_files_are_picked_up(tmp_path):
     assert read(Ctx) == 2.0
 
     # the whole run, at a small size, from the new files
+    small = smoke.settings(TEMPLATE)
     cell.hf = smoke.model.smoke_config(cell.hf)
-    cell.server = dict(cell.server, **smoke.SMOKE_SERVER["qwen2-1.5b.chat"])
-    cell.check = dict(cell.check, max_logit_gap=smoke.SMOKE_LIMIT[
-        "qwen2-1.5b.chat"], **smoke.SMOKE_CHECK)
+    cell.server = dict(cell.server, **small["server"])
+    cell.check = dict(cell.check, max_logit_gap=small["max_logit_gap"],
+                      **smoke.SMOKE_CHECK)
     r = smoke.run(cell, 2**31 + 3, seconds=3.0)
     assert r["correct"], r["checks"]
     assert r["metrics"]["dummy_done"]["value"] > 0
@@ -108,8 +114,8 @@ def test_traced_run_reports_the_new_metric(tmp_path, monkeypatch):
         "source": "program_counter", "layer": "serve.scheduler",
         "moves": "output_tok_s"}]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    cell = smoke.cell("qwen2-1.5b.chat")
-    found = harness.load_cell("qwen2-1.5b.chat", root=str(root))
+    cell = smoke.cell(TEMPLATE)
+    found = harness.load_cell(TEMPLATE, root=str(root))
     cell.per_layer, cell.bench_dir = found.per_layer, found.bench_dir
     # a CPU run has no published peaks; the test metric reads none
     monkeypatch.setitem(peaks.PEAKS, "cpu", {})
@@ -126,9 +132,14 @@ def test_every_entry_of_the_benchmark_has_its_files():
     b = os.path.join(ROOT, "bench")
     for c in bench["configs"]:
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
+        hf = smoke.model.load_config(c["name"])
+        for sub, name in (("arch", hf["model_type"]),
+                          ("reference", hf["reference"])):
+            assert os.path.isfile(os.path.join(b, sub, name + ".py"))
     for w in bench["workloads"]:
         for sub, name in (("cells", w["name"]), ("traffic", w["traffic"]),
-                          ("configs", w["config"])):
+                          ("configs", w["config"]),
+                          ("tests/cells", w["name"])):
             assert os.path.isfile(os.path.join(b, sub, name + ".json"))
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
@@ -136,3 +147,58 @@ def test_every_entry_of_the_benchmark_has_its_files():
         moved = e2e[m["moves"]]
         for w in m.get("workloads", []):
             assert "workloads" not in moved or w in moved["workloads"]
+
+
+#: a second architecture as new files alone, laid out as in a checkout:
+#: `swa_example`, a dense decoder without QKV bias, a 24-key window on
+#: alternate layers at the smoke size (`bench/arch/swa_example.py`)
+NEW_ARCH = os.path.join(HERE, "fixtures", "new_arch")
+
+
+def test_new_architecture_joins_by_new_files_alone(tmp_path):
+    root, bench = _checkout(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
+              if p.is_file()}
+    src = os.path.join(NEW_ARCH, "bench")
+    for d, _, files in os.walk(src):
+        for f in files:
+            dst = root / "bench" / os.path.relpath(os.path.join(d, f), src)
+            assert not dst.exists(), dst
+            dst.parent.mkdir(parents=True, exist_ok=True)
+            shutil.copy(os.path.join(d, f), dst)
+    with open(os.path.join(NEW_ARCH, "benchmark_entries.json")) as f:
+        entries = json.load(f)
+    bench["configs"] += entries["configs"]
+    bench["workloads"] += entries["workloads"]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    config = entries["configs"][0]["name"]
+    cell = entries["workloads"][0]["name"]
+
+    # the checkout's own tests, which take their configurations and
+    # cells from its BENCHMARK.json, now cover the new ones: served
+    # through `ContinuousBatcher(paged=True)` against the new reference,
+    # its float8 control, a broken step, its counts and readers
+    tests = [
+        f"bench/tests/test_reference.py::"
+        f"test_paged_prefill_and_decode_logits_match_reference[{config}]",
+        f"bench/tests/test_reference.py::"
+        f"test_served_gaps_read_zero_on_the_references_own_tokens[{config}]",
+        f"bench/tests/test_shapes.py::"
+        f"test_every_configuration_counts_work_in_each_layer[{config}]",
+        f"bench/tests/test_faults.py::test_sound_run_is_correct[{cell}]",
+        f"bench/tests/test_faults.py::"
+        f"test_broken_step_is_not_correct[{cell}-token_altered]",
+        f"bench/tests/test_control.py::"
+        f"test_float8_control_fails_where_the_program_passes[{cell}]",
+        "bench/tests/test_discovery.py::"
+        "test_every_entry_of_the_benchmark_has_its_files",
+    ]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", *tests],
+        capture_output=True, text=True, env=env, timeout=900, cwd=root)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+    assert f"{len(tests)} passed" in p.stdout, p.stdout[-2000:]
+    assert {p: p.read_bytes() for p in before} == before

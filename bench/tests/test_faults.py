@@ -10,7 +10,7 @@ import pytest
 import smoke
 from bench import harness
 
-CELLS = ["qwen2-1.5b.chat", "qwen2.5-14b-12l.long_decode"]
+CELLS = smoke.CELLS
 
 
 def token_altered(batcher):
@@ -57,8 +57,9 @@ def test_broken_step_is_not_correct(name, fault):
 
 def test_a_compile_in_the_window_stops_the_run():
     """A program the window calls that set-up did not warm: the run
-    stops rather than time the compiler. (The sessions cell: a chat
-    cell's ramp, which runs before the window, would compile it.)"""
+    stops rather than time the compiler. (A cell of set-up sessions
+    alone: an open-loop cell's ramp, which runs before the window, would
+    compile it.)"""
 
     def new_program(batcher):
         step = batcher._decode_paged
@@ -70,5 +71,5 @@ def test_a_compile_in_the_window_stops_the_run():
         batcher._decode_paged = recompiled
 
     with pytest.raises(harness.WindowCompiled):
-        smoke.run(smoke.cell("qwen2.5-14b-12l.long_decode"), 17,
+        smoke.run(smoke.cell(smoke.first_cell(open_loop=False)), 17,
                   seconds=1.0, sabotage=new_program)

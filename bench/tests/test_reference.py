@@ -7,18 +7,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import model
-from bench.reference import qwen2_f32 as ref
-
-CONFIGS = ["qwen2-1.5b", "qwen2.5-14b-12l"]
+import smoke
+from bench import check, model
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", smoke.CONFIGS)
 def test_paged_prefill_and_decode_logits_match_reference(name):
     from repro.models import decode_step_paged, init_paged_pool, \
         prefill_paged
 
     hf = model.smoke_config(model.load_config(name), torch_dtype="float32")
+    ref = check.reference(hf)
     cfg = model.program_config(hf, name)
     w = model.make_weights(hf, model.seed_key(2**33 + 1))
     params = model.program_params(hf, w)
@@ -49,9 +48,10 @@ def test_paged_prefill_and_decode_logits_match_reference(name):
     assert np.abs(got - want).max() <= 1e-4 * scale
 
 
-def test_served_gaps_read_zero_on_the_references_own_tokens():
-    hf = model.smoke_config(model.load_config("qwen2-1.5b"),
-                            torch_dtype="float32")
+@pytest.mark.parametrize("name", smoke.CONFIGS)
+def test_served_gaps_read_zero_on_the_references_own_tokens(name):
+    hf = model.smoke_config(model.load_config(name), torch_dtype="float32")
+    ref = check.reference(hf)
     w = model.make_weights(hf, model.seed_key(7))
     seq = list(np.random.default_rng(1).integers(0, hf["vocab_size"], 20))
     for _ in range(12):                          # greedy continuation

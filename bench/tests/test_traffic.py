@@ -2,6 +2,7 @@
 gives the same work in another order, slice by slice."""
 
 import collections
+import os
 
 import numpy as np
 import pytest
@@ -16,7 +17,13 @@ BURST = {"arrivals": {
     "shared_prefix": {"len_pool": [48, 96], "share": 0.5}}}
 
 
-@pytest.fixture(params=["chat", "long_decode", "burst"])
+#: every mix under `bench/traffic/`, and a bursty one with shared prefixes
+MIXES = sorted(f[:-len(".json")] for f in os.listdir(
+    os.path.join(traffic.BENCH_DIR, "traffic")) if f.endswith(".json"))
+ARRIVAL_MIXES = [m for m in MIXES if "arrivals" in traffic.load_mix(m)]
+
+
+@pytest.fixture(params=MIXES + ["burst"])
 def mix(request):
     if request.param == "burst":
         return BURST
@@ -72,10 +79,12 @@ def test_every_seed_gets_the_same_work(mix):
         -mix["arrivals"].get("ramp_s", 0))
 
 
-def test_each_slice_spans_the_length_distribution():
-    """Every 10 s slice of the chat window gets long and short prompts:
-    no seed piles the longest into one slice."""
-    mix = traffic.load_mix("chat")
+@pytest.mark.parametrize("name", ARRIVAL_MIXES)
+def test_each_slice_spans_the_length_distribution(name):
+    """Every slice of an open-loop window that holds five requests or
+    more gets long and short prompts: no seed piles the longest into one
+    slice."""
+    mix = traffic.load_mix(name)
     sl = _slice_of(mix, 51)
     pool = sorted(mix["arrivals"]["prompt_len_pool"])
     for seed in (3, 2**32 + 1, 77):
